@@ -117,6 +117,9 @@ def main():
                     help="exit 1 unless both request classes report finite "
                          "p50/p95 latencies in the one FrontDoorReport")
     args = ap.parse_args()
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
 
     models = [m.strip() for m in args.models.split(",") if m.strip()]
     rows, report, deployment = bench_mixed(

@@ -548,6 +548,9 @@ def main():
                          "across replica counts and the largest R reaches "
                          "2x the R=1 rate (after remeasure)")
     args = ap.parse_args()
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.scaling:
         return _scaling_main(args)

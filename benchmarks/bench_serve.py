@@ -89,6 +89,9 @@ if __name__ == "__main__":
     ap.add_argument("--json", type=pathlib.Path, default=None,
                     help="also write rows as JSON")
     args = ap.parse_args()
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
     rows = bench_serve()
     print("name,value,derived")
     for name, val, derived in rows:

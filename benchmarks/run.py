@@ -1,7 +1,8 @@
 """Benchmark harness — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV (one row per reported quantity) and
-writes results/bench_output.json.
+writes results/bench_output.json.  A section that raises is reported as an
+ERROR row, the remaining sections still run, and the run exits non-zero.
 """
 
 from __future__ import annotations
@@ -10,6 +11,7 @@ import json
 import pathlib
 import sys
 import time
+import traceback
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
@@ -36,14 +38,20 @@ SECTIONS = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
     all_rows = []
+    failed = []
     print("name,us_per_call,derived")
     for section, fn in SECTIONS:
         t0 = time.perf_counter()
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001 — benches must not kill the run
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
+            traceback.print_exc()
+            failed.append(section)
             rows = [(f"{section}/ERROR", 0.0, f"{type(e).__name__}: {e}")]
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -54,7 +62,11 @@ def main() -> None:
     out = pathlib.Path(__file__).resolve().parents[1] / "results" / "bench_output.json"
     out.parent.mkdir(parents=True, exist_ok=True)
     out.write_text(json.dumps(all_rows, indent=1))
+    if failed:
+        print(f"# FAILED sections: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -23,10 +23,10 @@ the equation graph:
 * **NSF003 host round-trips** — callback/infeed/outfeed primitives in a
   hot stage body block the device per dispatch.
 * **NSF004 donation** — off-CPU schedules must donate the fused
-  pipeline's inter-stage buffer (the lowered text carries an aliasing
-  annotation), CPU schedules must not (XLA:CPU ignores donation and
-  warns); either mismatch means ``compile_schedule``'s donation policy
-  and the artifact disagree.
+  pipeline's input buffer where an output has its shape and dtype (the
+  lowered text carries an aliasing annotation), CPU schedules must not
+  (XLA:CPU ignores donation and warns); either mismatch means
+  ``compile_schedule``'s donation policy and the artifact disagree.
 """
 
 from __future__ import annotations
@@ -152,12 +152,17 @@ def check_donation(sched, where) -> list:
     if sched.jit_fused is None or sched.input_specs is None \
             or sched.consts_spec is None:
         return []
+    from repro.serve.schedule import donation_usable
+
     plan = sched.plan or registry.get_plan()
     with registry.use_plan(plan):
         text = sched.jit_fused.lower(sched.consts_spec,
                                      sched.input_specs).as_text()
+        out = jax.eval_shape(sched.jit_fused, sched.consts_spec,
+                             sched.input_specs)
     donated = text.count("aliasing_output") + text.count("jax.buffer_donor")
-    if plan.platform != "cpu" and not donated:
+    usable = donation_usable(sched.input_specs, out)
+    if plan.platform != "cpu" and usable and not donated:
         return [finding(
             "NSF004", where,
             f"fused pipeline on {plan.platform!r} carries no donation "

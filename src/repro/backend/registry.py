@@ -314,13 +314,16 @@ def negotiate(platform: str | None = None,
     Forced lowerings skip the platform predicate (that is the point of a
     forced-fallback run) but keep the ``xla`` reference as the terminal
     fallback for call sites the forced lowering cannot serve (non-pow2
-    block dims).  Unknown platforms negotiate the all-``xla`` plan —
-    graceful degradation on backends no Pallas lowering claims.
+    block dims).  A platform outside :data:`PLATFORMS` raises: serving it
+    an all-``xla`` plan would hide the device behind the reference.
     """
     if platform is None:
         import jax
 
         platform = jax.default_backend()
+    if platform not in PLATFORMS:
+        raise ValueError(f"no lowering plan for platform {platform!r} "
+                         f"(known: {PLATFORMS})")
     source = "negotiated"
     if override is None:
         override = os.environ.get(ENV_VAR, "")
@@ -372,7 +375,9 @@ def record_selections() -> Iterator[list]:
     try:
         yield rec
     finally:
-        _RECORDERS.remove(rec)
+        # by identity: list.remove would drop the first *equal* recorder,
+        # which may be an enclosing scope's
+        _RECORDERS[:] = [r for r in _RECORDERS if r is not rec]
 
 
 def get_plan() -> LoweringPlan:

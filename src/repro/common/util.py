@@ -67,30 +67,31 @@ def round_up_pow2(n: int) -> int:
 
 
 def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    ``jax.sharding.set_mesh`` exists on newer jax; older releases use the
-    ``Mesh`` object itself as the context manager.
-    """
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    """Ambient-mesh context manager (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def shard_map_unreplicated(fn, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions.
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    The flag is ``check_vma`` on newer jax, ``check_rep`` before that; the
-    entry point moved from ``jax.experimental.shard_map`` to ``jax.shard_map``.
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
+    this sets nothing.  Otherwise the cache goes to ``.jax_cache`` at the
+    root of the checkout: a fixed path, because the path is part of the
+    cache key.  Call it from ``main()``, never at import, so tests stay
+    cache-free.  Returns the directory in force.
     """
-    import inspect
+    import os
+    import pathlib
 
-    try:
-        smap = jax.shard_map
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as smap  # type: ignore
-
-    flag = ("check_vma" if "check_vma" in inspect.signature(smap).parameters
-            else "check_rep")
-    return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **{flag: False})
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -677,14 +677,17 @@ def compile_reason_schedule(model: str, cfg, variant: str | None = None,
 
 def reason_engine(model: str, cfg, reason_cfg=None, consts=None,
                   variants: tuple[str, ...] | None = None,
-                  trace_graph: bool = True, plan=None):
+                  trace_graph: bool = True, plan=None,
+                  fused: bool | str = "auto", device=None):
     """Compile all (or the given) variants of a workload and wrap them in
     the generic N-stage ``ReasonEngine``.  ``reason_cfg.buckets`` (when
     set) compiles every variant with that tuple of batch-size buckets.
     ``consts`` (the workload's constant pytree) is bound onto the engine,
     which therefore implements the consts-free runtime protocol; with
     ``consts=None`` the schedules compile against abstract shapes and the
-    engine can only be inspected, not served."""
+    engine can only be inspected, not served.  ``fused`` goes to
+    :func:`compile_reason_schedule`; ``device`` is where the engine stages
+    its inputs (None = the default device)."""
     from repro.serve.reason import ReasonConfig, ReasonEngine
 
     entry = REASON_WORKLOADS.get(model)
@@ -696,21 +699,22 @@ def reason_engine(model: str, cfg, reason_cfg=None, consts=None,
         v: compile_reason_schedule(
             model, cfg, variant=v, consts=consts,
             batch_size=reason_cfg.buckets or reason_cfg.batch_size,
-            trace_graph=trace_graph, plan=plan)
+            trace_graph=trace_graph, plan=plan, fused=fused)
         for v in (variants or entry.variants)}
-    return ReasonEngine(schedules, reason_cfg, consts=consts)
+    return ReasonEngine(schedules, reason_cfg, consts=consts, device=device)
 
 
 def reason_engine_pool(model: str, cfg, reason_cfg=None, consts=None,
                        variants: tuple[str, ...] | None = None,
                        replicas: int = 1, trace_graph: bool = False,
-                       plan=None):
+                       plan=None, fused: bool | str = "auto"):
     """``replicas`` data-parallel :func:`reason_engine` copies behind one
     :class:`~repro.serve.replica.ReplicaPool`.
 
     Each replica gets the *same* constants (bit-identical answers
     whichever replica serves a request) ``jax.device_put`` onto its own
-    device — ``jax.devices()[i % ndev]`` — so jit executions of different
+    device — ``jax.devices()[i % ndev]`` — and stages its inputs there,
+    so jit executions of different
     replicas land on different devices and overlap (fake host devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` work the same
     way).  All replicas share ONE compiled schedule dict: stage jit caches
@@ -730,7 +734,7 @@ def reason_engine_pool(model: str, cfg, reason_cfg=None, consts=None,
     if replicas == 1:
         return reason_engine(model, cfg, reason_cfg, consts=consts,
                              variants=variants, trace_graph=trace_graph,
-                             plan=plan)
+                             plan=plan, fused=fused)
     if consts is None:
         raise ValueError("a replica pool needs real consts (answers must "
                          "be replica-invariant, so every replica binds the "
@@ -739,22 +743,24 @@ def reason_engine_pool(model: str, cfg, reason_cfg=None, consts=None,
     engines = []
     schedules = None
     for i in range(replicas):
-        c = _jax.device_put(consts, devs[i % len(devs)])
+        dev = devs[i % len(devs)]
+        c = _jax.device_put(consts, dev)
         rcfg = _dc.replace(reason_cfg)
         if schedules is None:
             eng = reason_engine(model, cfg, rcfg, consts=c,
                                 variants=variants, trace_graph=trace_graph,
-                                plan=plan)
+                                plan=plan, fused=fused, device=dev)
             schedules = eng.schedules
         else:
-            eng = ReasonEngine(schedules, rcfg, consts=c)
+            eng = ReasonEngine(schedules, rcfg, consts=c, device=dev)
         engines.append(eng)
     return ReplicaPool(engines)
 
 
-def lm_engine(arch_id: str, serve_cfg=None, key=None, tp: int = 1,
+def lm_engine(arch_id: str, cfg, serve_cfg=None, key=None, tp: int = 1,
               device=None):
-    """Materialize a smoke-scale arch and wrap it in the slot-pool LM
+    """Materialize arch ``arch_id`` at model config ``cfg`` (its
+    ``make_smoke()`` or ``make_full()``) and wrap it in the slot-pool LM
     ``Engine`` with params bound — the LM counterpart of
     :func:`reason_engine`, so both engine classes come out implementing
     the unified runtime protocol.  Returns ``(engine, model_cfg)``
@@ -771,8 +777,9 @@ def lm_engine(arch_id: str, serve_cfg=None, key=None, tp: int = 1,
     Needs ``tp <= jax.device_count()`` (fake host devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 
-    ``device`` pins the (unsharded) params onto one device — the
-    data-parallel replica path (mutually exclusive with ``tp > 1``)."""
+    ``device`` pins the (unsharded) params, and the engine's KV caches and
+    decode inputs, onto one device — the data-parallel replica path
+    (mutually exclusive with ``tp > 1``)."""
     import jax as _jax
 
     from repro.configs import ARCHS
@@ -784,7 +791,6 @@ def lm_engine(arch_id: str, serve_cfg=None, key=None, tp: int = 1,
         raise ValueError("pass tp= (tensor-parallel) or device= (replica "
                          "placement), not both")
     arch = ARCHS[arch_id]
-    cfg = arch.make_smoke()
     serve_cfg = serve_cfg or ServeConfig()
     spec = model_spec(arch, cfg)
     params = nninit.materialize(spec,
@@ -806,10 +812,11 @@ def lm_engine(arch_id: str, serve_cfg=None, key=None, tp: int = 1,
     elif device is not None:
         params = _jax.device_put(params, device)
     step, init_caches = serve_fns(arch, cfg, max_len=serve_cfg.max_len)
-    return Engine(step, init_caches, serve_cfg, params=params), cfg
+    return Engine(step, init_caches, serve_cfg, params=params,
+                  device=device), cfg
 
 
-def lm_engine_pool(arch_id: str, serve_cfg=None, key=None,
+def lm_engine_pool(arch_id: str, cfg, serve_cfg=None, key=None,
                    replicas: int = 1, tp: int = 1):
     """``replicas`` data-parallel LM engines behind one ``ReplicaPool``
     (each replica's params on its own device, same PRNG key so token
@@ -827,11 +834,11 @@ def lm_engine_pool(arch_id: str, serve_cfg=None, key=None,
             f"replicas={replicas} with tp={tp}: combined data x tensor "
             "parallel LM serving is not wired up — pick one axis")
     if replicas == 1:
-        return lm_engine(arch_id, serve_cfg, key=key, tp=tp)
+        return lm_engine(arch_id, cfg, serve_cfg, key=key, tp=tp)
     devs = _jax.devices()
-    engines, cfg = [], None
+    engines = []
     for i in range(replicas):
-        eng, cfg = lm_engine(arch_id, serve_cfg, key=key,
+        eng, cfg = lm_engine(arch_id, cfg, serve_cfg, key=key,
                              device=devs[i % len(devs)])
         engines.append(eng)
     return ReplicaPool(engines), cfg

@@ -61,10 +61,12 @@ def _divisors(n: int):
 def search(n_params: float, n_active: float, d_model: int, n_layers: int,
            seq: int, global_batch: int, chips: int = 256,
            bytes_per_param: float = 2.0, moment_bytes: float = 8.0,
-           kv_bytes_per_tok: float = 0.0, train: bool = True) -> list[MeshPoint]:
+           kv_bytes_per_tok: float = 0.0, train: bool = True,
+           hw: dict = HW) -> list[MeshPoint]:
     """Rank mesh factorizations for one (arch × shape).
 
     Analytic; no compile. Returns points sorted by bound_s (feasible first).
+    ``hw`` is the per-chip peaks table (``launch.mesh.device_peaks``).
     """
     tokens = global_batch * seq
     passes = 3 if train else 1
@@ -78,19 +80,19 @@ def search(n_params: float, n_active: float, d_model: int, n_layers: int,
           for accum in ((1, 4, 16) if train else (1,)):
             eff_passes = passes + (1 if remat else 0)
             f = 2 * n_active * tokens * eff_passes
-            compute = f / (chips * HW["peak_flops_bf16"])
+            compute = f / (chips * hw["peak_flops_bf16"])
             # memory: weights stream once per pass per chip-shard per
             # microbatch + activations (residual stream, halved by remat)
             w_bytes = n_params * bytes_per_param / model
             act = tokens / data * d_model * 2.0 * n_layers * (2 if not remat else 1)
-            memory = (w_bytes * eff_passes * accum + act) / HW["hbm_bw"]
+            memory = (w_bytes * eff_passes * accum + act) / hw["hbm_bw"]
             # collectives: TP psum of activations per layer (2×), DP grad
             # reduce-scatter+all-gather of the model shard
             tp = 0.0 if model == 1 else \
                 2 * n_layers * (tokens / data) * d_model * 2.0
             dp = 0.0 if (data == 1 or not train) else \
                 2 * n_params * bytes_per_param / model
-            collective = (tp + dp) / (HW["ici_bw_per_link"] * HW["ici_links"])
+            collective = (tp + dp) / (hw["ici_bw_per_link"] * hw["ici_links"])
             # live activations: one microbatch's layer boundaries, sharded
             # over the model axis too (sequence-sharded saves)
             act_live = act / (accum * model)
@@ -99,7 +101,7 @@ def search(n_params: float, n_active: float, d_model: int, n_layers: int,
                    + act_live * 2 + tokens / data * kv_bytes_per_tok)
             points.append(MeshPoint(data, model, remat, accum, compute, memory,
                                     collective, hbm / 1e9,
-                                    hbm < HW["hbm_bytes"]))
+                                    hbm < hw["hbm_bytes"]))
     points.sort(key=lambda p: (not p.feasible, p.bound_s))
     return points
 
@@ -114,7 +116,8 @@ def serving_search(n_params: float, n_active: float, d_model: int,
                    n_layers: int, seq: int, batch: int, devices: int,
                    kv_bytes_per_tok: float = 0.0,
                    bytes_per_param: float = 4.0,
-                   max_model: int | None = None) -> list[MeshPoint]:
+                   max_model: int | None = None,
+                   hw: dict = HW) -> list[MeshPoint]:
     """Mesh DSE in **serving mode**: the factorization deploy() co-searches.
 
     Serving differs from training everywhere the cost model cares: one
@@ -136,7 +139,7 @@ def serving_search(n_params: float, n_active: float, d_model: int,
     pts = search(n_params, n_active, d_model, n_layers, seq,
                  global_batch=batch, chips=devices,
                  bytes_per_param=bytes_per_param, moment_bytes=0.0,
-                 kv_bytes_per_tok=kv_bytes_per_tok, train=False)
+                 kv_bytes_per_tok=kv_bytes_per_tok, train=False, hw=hw)
     if max_model is not None:
         pts = [p for p in pts if p.model <= max_model]
     if not pts:

@@ -13,13 +13,10 @@ from jax.sharding import PartitionSpec as PS
 
 
 def _ambient_axes() -> tuple:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return ()
-        return tuple(mesh.axis_names)
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return ()
+    return tuple(mesh.axis_names)
 
 
 def maybe_constrain(x: jax.Array, axes: tuple):
@@ -41,10 +38,7 @@ def maybe_constrain(x: jax.Array, axes: tuple):
             spec.append(a if a in names else None)
     while spec and spec[-1] is None:
         spec.pop()
-    try:
-        return jax.lax.with_sharding_constraint(x, PS(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, PS(*spec))
 
 
 def batch_seq_heads(x: jax.Array):

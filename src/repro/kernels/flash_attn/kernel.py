@@ -65,7 +65,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     scale: float, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
+                    bk: int = 128, interpret: bool) -> jax.Array:
     """q: (BH, Sq, hd); k, v: (BH, Skv, hd) -> (BH, Sq, hd)."""
     bh, sq, hd = q.shape
     skv = k.shape[1]

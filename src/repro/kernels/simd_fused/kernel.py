@@ -42,7 +42,7 @@ def _match_prob_kernel(q_ref, d_ref, o_ref, *, temp: float, blocks: int):
 
 @functools.partial(jax.jit, static_argnames=("temp", "interpret", "tile_n"))
 def fused_match_prob(q: jax.Array, dictionary: jax.Array, temp: float = 1.0,
-                     *, interpret: bool = True, tile_n: int = 128) -> jax.Array:
+                     *, interpret: bool, tile_n: int = 128) -> jax.Array:
     """q: (N, B, d), dictionary: (M, B, d) -> probs (N, M)."""
     n, b, d = q.shape
     m = dictionary.shape[0]

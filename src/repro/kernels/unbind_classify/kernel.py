@@ -12,7 +12,10 @@ head, accumulating logits across blocks without ever writing the unbound
 codes back to HBM.
 
 Grid: (N / tile_n, K, B) with the VSA block axis innermost so each output
-tile (tn, 1, C) stays resident while its B partial products accumulate.
+tile stays resident while its B partial products accumulate.  The wrapper
+lays the operands out so every block's last two dims are Mosaic-tileable:
+queries as ``(B, N, d)`` tiles of ``(tn, d)``, keys as ``(K, B, 1, d)``
+rows, logits as ``(K, N, C)`` tiles of ``(tn, C)``.
 """
 
 from __future__ import annotations
@@ -23,20 +26,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.circ_conv.kernel import _circulant
+from repro.kernels.circ_conv.kernel import _circulant, rev_fixed0
 
 
 def _unbind_classify_kernel(x_ref, k_ref, w_ref, b_ref, o_ref):
-    x = x_ref[:, 0, :].astype(jnp.float32)        # (tn, d)
-    key = k_ref[0, 0, :].astype(jnp.float32)      # (d,)
-    # corr(key, x)[n] = Σ_j key[j]·x[(n+j)%d] = Σ_m x[m]·roll(key, n)[m]
-    c = _circulant(key[None], 1)[0]               # (d, d): c[n] = roll(key, n)
+    x = x_ref[...].astype(jnp.float32)            # (tn, d): reversed queries
+    key = k_ref[...].astype(jnp.float32)          # (1, d): reversed key
+    # corr(key, x)[n] = Σ_m x[m]·key[(m-n)%d] = Σ_p x_rev[p]·key_rev[(n+p)%d]
+    c = _circulant(key)[0]                        # (d, d): c[n, p] = key_rev[n+p]
     unbound = jax.lax.dot_general(
         x, c,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                             # (tn, d)
-    w = w_ref[0].astype(jnp.float32)              # (d, C)
+    w = w_ref[...].astype(jnp.float32)            # (d, C)
     part = jax.lax.dot_general(
         unbound, w,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -45,37 +48,39 @@ def _unbind_classify_kernel(x_ref, k_ref, w_ref, b_ref, o_ref):
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        o_ref[:, 0, :] = b_ref[0] + part
+        o_ref[...] = b_ref[...] + part
 
     @pl.when(pl.program_id(2) > 0)
     def _accumulate():
-        o_ref[:, 0, :] += part
+        o_ref[...] += part
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_n"))
 def fused_unbind_classify(keys: jax.Array, x: jax.Array, w: jax.Array,
-                          b: jax.Array, *, interpret: bool = True,
+                          b: jax.Array, *, interpret: bool,
                           tile_n: int = 128) -> jax.Array:
     """keys: (K, B, d), x: (N, B, d), w: (B, d, C), b: (1, C) -> (N, K, C)."""
     n, blocks, d = x.shape
     k = keys.shape[0]
     c_dim = w.shape[-1]
-    tn = min(tile_n, max(8, n))
+    tn = min(tile_n, -(-n // 8) * 8)
     pad = (-n) % tn
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+    xt = jnp.pad(jnp.swapaxes(rev_fixed0(x), 0, 1),
+                 ((0, 0), (0, pad), (0, 0)))
     out = pl.pallas_call(
         _unbind_classify_kernel,
         name="fused_unbind_classify",
         grid=((n + pad) // tn, k, blocks),
         in_specs=[
-            pl.BlockSpec((tn, 1, d), lambda i, kc, blk: (i, blk, 0)),
-            pl.BlockSpec((1, 1, d), lambda i, kc, blk: (kc, blk, 0)),
-            pl.BlockSpec((1, d, c_dim), lambda i, kc, blk: (blk, 0, 0)),
+            pl.BlockSpec((None, tn, d), lambda i, kc, blk: (blk, i, 0)),
+            pl.BlockSpec((None, None, 1, d),
+                         lambda i, kc, blk: (kc, blk, 0, 0)),
+            pl.BlockSpec((None, d, c_dim), lambda i, kc, blk: (blk, 0, 0)),
             pl.BlockSpec((1, c_dim), lambda i, kc, blk: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tn, 1, c_dim), lambda i, kc, blk: (i, kc, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + pad, k, c_dim), jnp.float32),
+        out_specs=pl.BlockSpec((None, tn, c_dim),
+                               lambda i, kc, blk: (kc, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, n + pad, c_dim), jnp.float32),
         interpret=interpret,
-    )(x, keys, w, b)
-    return out[:n]
+    )(xt, rev_fixed0(keys)[:, :, None, :], w, b)
+    return jnp.swapaxes(out[:, :n], 0, 1)

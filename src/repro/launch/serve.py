@@ -198,10 +198,11 @@ def serve_frontdoor(args):
 
 
 def serve_lm(args):
+    from repro.configs import ARCHS
     from repro.serve.engine import Request, ServeConfig
 
     eng, cfg = cbase.lm_engine_pool(
-        args.arch,
+        args.arch, ARCHS[args.arch].make_smoke(),
         ServeConfig(max_new_tokens=args.max_new, max_slots=args.slots,
                     max_len=args.cache_len, decode_block=args.decode_block,
                     temperature=args.temperature, top_k=args.top_k,
@@ -224,8 +225,10 @@ def serve_lm(args):
                         for i, e in enumerate(eng.replicas))
     else:
         util = f"{eng.utilization():.0%}"
+    dev = jax.devices()[0]
     print(f"[serve] {dt:.1f}s total, {toks/dt:.1f} tok/s, "
-          f"slot utilization {util} (CPU smoke config)")
+          f"slot utilization {util} (smoke config on {dev.platform} "
+          f"{dev.device_kind} x{jax.device_count()})")
     print(f"[serve] sample output ids: {results[0].tokens[:12].tolist()}")
     return results
 
@@ -303,6 +306,9 @@ def main():
                          "interactive=3,standard=5,batch=2")
     args = ap.parse_args()
 
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
     if args.replicas < 1 or args.tp < 1:
         raise SystemExit(f"--replicas/--tp must be >= 1 "
                          f"(got {args.replicas}/{args.tp})")

@@ -354,8 +354,8 @@ class Deployment:
 
 
 def _mesh_plan(n_params: float, d_model: int, n_layers: int, seq: int,
-               batch: int, ndev: int, replicas, tp: int,
-               kv_bytes_per_tok: float = 0.0):
+               batch: int, ndev: int, replicas, tp: int, hw: dict,
+               kv_bytes_per_tok: float = 0.0, bytes_per_param: float = 4.0):
     """Resolve (replica count, deployed MeshPoint) for one model.
 
     ``replicas="auto"`` lets the serving-mode mesh DSE pick: search the
@@ -363,7 +363,8 @@ def _mesh_plan(n_params: float, d_model: int, n_layers: int, seq: int,
     winner's data axis.  An explicit/None replica count is honored as-is
     — the search then runs at ``chips = replicas × tp`` so the recorded
     point describes the factorization actually deployed (its ``bound_s``
-    is the per-step roofline prediction for that mesh).
+    is the per-step roofline prediction for that mesh).  ``hw`` is the
+    device's peaks table (``launch.mesh.device_peaks``).
     """
     from repro.core import meshdse
 
@@ -371,7 +372,7 @@ def _mesh_plan(n_params: float, d_model: int, n_layers: int, seq: int,
         pts = meshdse.serving_search(
             n_params, n_params, d_model, n_layers, seq, b,
             devices=chips, kv_bytes_per_tok=kv_bytes_per_tok,
-            max_model=tp)
+            bytes_per_param=bytes_per_param, max_model=tp, hw=hw)
         return [p for p in pts if p.model == tp] or pts
 
     if replicas == "auto":
@@ -400,7 +401,11 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
     and/or servable LM arch ids (llama3.2-3b, stablelm-3b, ...), freely
     mixed.  ``options[model]`` passes per-model config kwargs (NSAI:
     ``make_config`` knobs like ``d`` / ``nn_precision`` plus an optional
-    ``variant``; LM: ``ServeConfig`` field overrides).
+    ``variant`` and ``schedule``; LM: ``ServeConfig`` field overrides plus
+    ``size``, ``"smoke"`` (default) or ``"full"`` for the arch's published
+    widths).  An NSAI ``schedule`` replaces the DSE-derived one;
+    ``"fused"`` serves one dispatch per group even where the fused
+    pipeline is only epsilon-equivalent to the staged one.
 
     For each NSAI workload the serving configuration is *derived*, not
     hand-set: the staged pipeline's dataflow graph is traced, explored by
@@ -425,9 +430,11 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
     ``Deployment.report()["analysis"]``.
     """
     import jax
+    import jax.numpy as jnp
 
     from repro.configs import base as cbase
     from repro.core import dse
+    from repro.launch.mesh import device_peaks
     from repro.serve import runtime as rt
     from repro.serve import schedule as sch
     from repro.serve.engine import ServeConfig
@@ -456,6 +463,7 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
     mesh: dict[str, Any] = {}
     replicas: dict[str, int] = {}
     ndev = budget.devices or jax.device_count()
+    hw = device_peaks()
     tp_eff = budget.tp or 1
     root = jax.random.PRNGKey(seed)
     for i, m in enumerate(models):
@@ -464,6 +472,7 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
         if m in cbase.REASON_WORKLOADS:
             entry = cbase.REASON_WORKLOADS[m]
             variant = opts.pop("variant", None) or entry.variants[0]
+            schedule = opts.pop("schedule", None)
             cfg = entry.make_config(**opts)
             # generator step: trace the exact pipeline the schedule will
             # execute (abstract consts — nothing materialized yet) and
@@ -485,15 +494,17 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
                 float(n_params), getattr(cfg, "d", 128),
                 max(1, len(entry.stage_specs(cfg, variant))), seq=1,
                 batch=budget.max_batch, ndev=ndev,
-                replicas=budget.replicas, tp=1)
+                replicas=budget.replicas, tp=1, hw=hw)
             eng = cbase.reason_engine_pool(
                 m, cfg,
                 ReasonConfig(batch_size=plan.batch_size,
-                             schedule=plan.schedule, variant=variant,
+                             schedule=schedule or plan.schedule,
+                             variant=variant,
                              max_inflight=plan.max_inflight,
                              buckets=plan.buckets),
                 consts=consts, variants=(variant,), replicas=r,
-                trace_graph=False, plan=lowering_plan)
+                trace_graph=False, plan=lowering_plan,
+                fused=True if schedule == "fused" else "auto")
             # fused-pipeline negotiation: when the compiled schedule's
             # fused variant is provably bit-identical under the deployment
             # plan, serve one dispatch per admission group instead of K
@@ -502,7 +513,7 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
             # share one compiled schedule but carry their own cfg copy,
             # so the upgrade applies per replica.
             subs = eng.replicas if hasattr(eng, "replicas") else [eng]
-            if plan.schedule == "overlap" and \
+            if schedule is None and plan.schedule == "overlap" and \
                     subs[0].schedules[variant].fused_ok:
                 for sub in subs:
                     sub.cfg.schedule = "fused"
@@ -511,6 +522,22 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
         else:
             # resolve_models already validated every name against the
             # frontdoor registry, so non-NSAI names are servable LM archs
+            from repro.configs import ARCHS
+
+            size = opts.pop("size", "smoke")
+            if size not in ("smoke", "full"):
+                raise ValueError(f"{m}: size must be 'smoke' or 'full', "
+                                 f"got {size!r}")
+            if size == "full":
+                # published widths, weights stored at the compute dtype:
+                # the decode scan hoists the per-step f32->bf16 weight
+                # casts, so f32 storage needs 1.5x the params in HBM
+                # (stablelm-3b: 16.3 GB against the v5e's 15.75 GB)
+                full = ARCHS[m].make_full()
+                mcfg = dataclasses.replace(full,
+                                           param_dtype=full.compute_dtype)
+            else:
+                mcfg = ARCHS[m].make_smoke()
             scfg = dataclasses.replace(
                 ServeConfig(max_slots=budget.max_slots,
                             max_len=budget.max_len,
@@ -519,9 +546,7 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
             # mesh co-search: LM decode may take a real TP axis through
             # distributed.sharding_rules, so the model axis is budget.tp;
             # the KV term comes from the arch config (bytes per resident
-            # token across every layer's K+V, fp32 smoke params)
-            from repro.configs import ARCHS
-            mcfg = ARCHS[m].make_smoke()
+            # token across every layer's K+V, 4 bytes an element)
             kv_bytes = (getattr(mcfg, "n_layers", 1) * 2
                         * getattr(mcfg, "n_kv_heads",
                                   getattr(mcfg, "n_heads", 1))
@@ -531,9 +556,10 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
                 getattr(mcfg, "d_model", 128),
                 getattr(mcfg, "n_layers", 1), seq=budget.max_len,
                 batch=budget.max_slots, ndev=ndev,
-                replicas=budget.replicas, tp=tp_eff,
-                kv_bytes_per_tok=kv_bytes)
-            eng, cfg = cbase.lm_engine_pool(m, scfg, key=key,
+                replicas=budget.replicas, tp=tp_eff, hw=hw,
+                kv_bytes_per_tok=kv_bytes,
+                bytes_per_param=float(jnp.dtype(mcfg.param_dtype).itemsize))
+            eng, cfg = cbase.lm_engine_pool(m, mcfg, scfg, key=key,
                                             replicas=r, tp=tp_eff)
             classes[m], designs[m], plans[m] = "lm", None, None
             variants[m] = None

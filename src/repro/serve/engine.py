@@ -145,12 +145,14 @@ class Engine:
     stamps (the front-door injects its own so queue/service latencies share
     one origin); ``wall`` is the real wall-clock the throughput accounting
     reads — separate so a virtual front-door clock never distorts measured
-    rates, injectable so the accounting itself is testable.
+    rates, injectable so the accounting itself is testable.  ``device``
+    is where the KV caches and per-block inputs live (None = the default
+    device); a replica passes the device its params were put on.
     """
 
     def __init__(self, decode_step: Callable, init_caches: Callable,
                  cfg: ServeConfig, params=None, clock=time.perf_counter,
-                 wall=time.perf_counter):
+                 wall=time.perf_counter, device=None):
         # configs.base.serve_fns tags init_caches for archs whose cumulative
         # recurrent state would be silently corrupted by bucketed pad steps —
         # honor the tag so no caller has to remember to set the flag
@@ -160,6 +162,7 @@ class Engine:
         self.cfg = cfg
         self.init_caches = init_caches
         self.params = params
+        self.device = device
         self.clock = clock
         self.wall = wall
         self._raw_decode_step = decode_step
@@ -326,10 +329,19 @@ class Engine:
                 f"request {req.uid}: prompt {plen} + budget {budget} "
                 f"exceeds max_len {self.cfg.max_len}")
 
+    def _put(self, x):
+        """Host array -> the engine's device."""
+        return jax.device_put(x, self.device)
+
+    def _new_caches(self):
+        """A zeroed cache pool, allocated on the engine's device."""
+        with jax.default_device(self.device):
+            return self.init_caches(self.cfg.max_slots)
+
     def _ensure_pool(self):
         if self._caches is None:
             cfg = self.cfg
-            self._caches = self.init_caches(cfg.max_slots)
+            self._caches = self._new_caches()
             self._state = {
                 "tok": np.full((cfg.max_slots,), cfg.pad_id, np.int32),
                 "pos": np.zeros((cfg.max_slots,), np.int32),
@@ -389,12 +401,12 @@ class Engine:
                 if rec is not None and rec.dispatch_t is None:
                     rec.dispatch_t = self.clock()
 
-            scratch = self.init_caches(cfg.max_slots)
-            scratch, last_logits = self._prefill(self.params, scratch,
-                                                 jnp.asarray(tokens),
-                                                 jnp.asarray(plens))
+            scratch, last_logits = self._prefill(self.params,
+                                                 self._new_caches(),
+                                                 self._put(tokens),
+                                                 self._put(plens))
             self._caches = self._merge(self._caches, scratch,
-                                       jnp.asarray(admit))
+                                       self._put(admit))
             self.stats["prefills"] += 1
 
             # first token: sample from each admitted request's own stream at
@@ -402,8 +414,8 @@ class Engine:
             for slot_idx, req in items:
                 state["keys"][slot_idx] = self._request_key(req.uid)
                 state["gen"][slot_idx] = 0
-            sub = jax.vmap(jax.random.fold_in)(jnp.asarray(state["keys"]),
-                                               jnp.asarray(state["gen"]))
+            sub = jax.vmap(jax.random.fold_in)(self._put(state["keys"]),
+                                               self._put(state["gen"]))
             first = np.asarray(self._sample_jit(last_logits, sub))
             for slot_idx, req in items:
                 state["tok"][slot_idx] = first[slot_idx]
@@ -451,10 +463,8 @@ class Engine:
         t0 = self.wall()
         (caches, tok, pos, active, budget, gen, toks, valid) = \
             self._decode_block(
-                self.params, self._caches, jnp.asarray(state["tok"]),
-                jnp.asarray(state["pos"]), jnp.asarray(state["active"]),
-                jnp.asarray(state["budget"]), jnp.asarray(state["keys"]),
-                jnp.asarray(state["gen"]))
+                self.params, self._caches, *(self._put(state[k]) for k in (
+                    "tok", "pos", "active", "budget", "keys", "gen")))
         self._caches = caches
         toks, valid = np.asarray(toks), np.asarray(valid)
         self.stats["decode_time_s"] += self.wall() - t0

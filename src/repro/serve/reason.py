@@ -77,7 +77,6 @@ import time
 from typing import Iterable, Mapping
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import runtime as rt
@@ -180,12 +179,14 @@ class ReasonEngine:
     its own so queue/service latencies share one origin); ``wall`` is the
     real wall-clock the throughput accounting reads — separate so a
     virtual front-door clock never distorts measured rates, injectable so
-    the accounting itself is testable.
+    the accounting itself is testable.  ``device`` is where staged inputs
+    land (None = the default device): a replica passes the device its
+    ``consts`` live on, so its groups never route through device 0.
     """
 
     def __init__(self, schedules: StagedSchedule | Mapping[str, StagedSchedule],
                  cfg: ReasonConfig, consts=None, clock=time.perf_counter,
-                 wall=time.perf_counter):
+                 wall=time.perf_counter, device=None):
         if isinstance(schedules, StagedSchedule):
             schedules = {schedules.variant: schedules}
         if not schedules:
@@ -209,6 +210,7 @@ class ReasonEngine:
                              f"compiled: {sorted(self.schedules)}")
         self.cfg = cfg
         self.consts = consts
+        self.device = device
         self.clock = clock
         self.wall = wall
         self.stats = _fresh_stats()
@@ -267,7 +269,7 @@ class ReasonEngine:
             x = np.stack(leaves)
             if pad:
                 x = np.concatenate([x, np.repeat(x[-1:], pad, axis=0)])
-            return jnp.asarray(x)
+            return jax.device_put(x, self.device)
 
         return jax.tree.map(stack, *trees), bucket
 

@@ -12,8 +12,10 @@ to shard admission groups across devices.
 
 - **least-inflight dispatch**: ``submit`` routes each admission group to
   the replica with the fewest dispatched-but-undrained groups (ties break
-  to the lowest index, so routing is deterministic for a given arrival
-  order).  Each replica keeps its own depth-k in-flight window — the pool
+  round-robin from the replica after the last one chosen, so routing is
+  deterministic for a given arrival order and a light load that drains
+  every group before the next still spreads over every device).  Each
+  replica keeps its own depth-k in-flight window — the pool
   never collapses them into one queue, so k × N groups can be resident.
 - **answer invariance**: answers are bit-identical whichever replica
   serves a request, because every replica is built from the *same*
@@ -102,6 +104,7 @@ class ReplicaPool:
         # truth is GroupRecord.replica on every record)
         self.dispatched_groups = [0] * len(replicas)
         self.dispatched_requests = [0] * len(replicas)
+        self._next = 0                # round-robin tie-break origin
 
     def __len__(self) -> int:
         return len(self.replicas)
@@ -148,13 +151,15 @@ class ReplicaPool:
     def submit(self, group, **kw) -> GroupRecord:
         """Dispatch one admission group to the least-loaded replica.
 
-        Least-inflight, ties to the lowest index: a burst of back-to-back
-        groups round-robins across idle replicas, a slow replica stops
-        receiving work until it drains.  The returned record carries the
-        chosen ``replica`` index.
+        Least-inflight, ties round-robin: groups rotate across idle
+        replicas whether or not the earlier ones have drained, and a slow
+        replica stops receiving work until it drains.  The returned record
+        carries the chosen ``replica`` index.
         """
-        i = min(range(len(self.replicas)),
-                key=lambda j: (self.replicas[j].inflight, j))
+        n = len(self.replicas)
+        i = min(range(n), key=lambda j: (self.replicas[j].inflight,
+                                         (j - self._next) % n))
+        self._next = (i + 1) % n
         rec = self.replicas[i].submit(group, **kw)
         rec.replica = i
         self.dispatched_groups[i] += 1
@@ -258,3 +263,4 @@ class ReplicaPool:
         self.runs = []
         self.dispatched_groups = [0] * len(self.replicas)
         self.dispatched_requests = [0] * len(self.replicas)
+        self._next = 0

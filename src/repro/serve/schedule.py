@@ -284,6 +284,17 @@ def _fused_conformance(staged_sel: list, fused_sel: list
     return ("exact" if exact else "epsilon"), eps, tuple(diff)
 
 
+def donation_usable(input_specs, output_specs) -> bool:
+    """True when some output leaf has an input leaf's shape and dtype —
+    the only case in which donating the staged input lets XLA write an
+    output into it.  Otherwise JAX drops the donation (and warns)."""
+    def avals(tree):
+        return {(tuple(x.shape), np.dtype(x.dtype))
+                for x in jax.tree.leaves(tree)}
+
+    return bool(avals(input_specs) & avals(output_specs))
+
+
 def _abstract(tree):
     """ShapeDtypeStruct skeleton of a pytree (non-array leaves pass through)."""
     return jax.tree.map(
@@ -293,11 +304,18 @@ def _abstract(tree):
 
 def _plan_scoped(fn: Callable, plan: registry.LoweringPlan) -> Callable:
     """Bind a stage fn to a LoweringPlan: tracing (and hence the lowering
-    choices jit bakes into its cache) always happens under ``plan``."""
+    choices jit bakes into its cache) always happens under ``plan``.
+
+    Stages also trace at ``highest`` matmul precision.  Workloads state
+    reduced precision through operand dtypes (bf16, int8), so an f32
+    product must be f32-accurate; XLA:TPU's default runs it as one bf16
+    pass, which moved NVSA's served logprobs by 0.07 from the reference on
+    a v5e.  XLA:CPU computes f32 products in f32 either way.
+    """
 
     @functools.wraps(fn)
     def scoped(consts, bufs):
-        with registry.use_plan(plan):
+        with registry.use_plan(plan), jax.default_matmul_precision("highest"):
             return fn(consts, bufs)
 
     return scoped
@@ -429,9 +447,12 @@ def compile_schedule(workload: str, stages: tuple[StageSpec, ...] | list,
         else:
             # same stage fns composed under the same plan: trivially exact
             fused_equivalence, fused_eps = "exact", 0.0
-        # donate the staged input buffer so XLA reuses it for the
-        # inter-stage intermediates (CPU does not implement donation)
-        donate = (1,) if plan.platform != "cpu" else ()
+        # donate the staged input buffer so XLA can write an output into
+        # it — where some output has its shape (CPU does not implement
+        # donation)
+        donate = (1,) if plan.platform != "cpu" and (
+            input_specs is None
+            or donation_usable(input_specs, fused_out)) else ()
         jit_fused = jax.jit(composed, donate_argnums=donate)
 
     return StagedSchedule(

@@ -98,14 +98,12 @@ def test_nsf001_ignores_downcast_under_float_precision():
 
 
 def test_nsf001_f64_upcast():
-    from jax.experimental import enable_x64
-
     def fn(consts, bufs):
         wide = jax.lax.convert_element_type(bufs["x"], jnp.float64)
         return {"x": wide.astype(jnp.float32)}
 
     sched = _FakeSched([_Stage("drift", "nn", fn)])
-    with enable_x64():
+    with jax.enable_x64():
         rep = artifacts.check_schedule(sched)
     assert "NSF001" in _rules_of(rep)
     assert any("float64" in f.message for f in rep.findings)
@@ -147,6 +145,19 @@ def test_nsf004_off_cpu_fused_without_donation():
     rep = artifacts.check_schedule(sched)
     assert _rules_of(rep) == ["NSF004"]
     assert not rep.ok
+
+
+def test_nsf004_off_cpu_fused_no_reusable_buffer_is_clean():
+    """An input no output can reuse (RAVEN panels in, logprobs out) has
+    nothing to donate: JAX would drop the annotation anyway."""
+    def fused(consts, bufs):
+        return {"y": bufs["x"].sum(axis=1)}
+
+    sched = _FakeSched([], plan=registry.negotiate(platform="tpu",
+                                                   override=""),
+                       jit_fused=jax.jit(fused))
+    rep = artifacts.check_schedule(sched)
+    assert rep.findings == []
 
 
 def test_nsf004_cpu_fused_with_donation_warns():

@@ -33,9 +33,10 @@ def test_negotiate_per_platform(platform, head, interpret):
 
 
 def test_negotiate_unknown_platform_falls_back_to_xla():
-    plan = registry.negotiate(platform="metal", override="")
-    assert all(low.is_ref for low in
-               (plan.lowering(k) for k in registry.KERNELS))
+    """An unknown platform gets no plan at all: an all-``xla`` fallback
+    would serve the reference and hide the device."""
+    with pytest.raises(ValueError, match="metal"):
+        registry.negotiate(platform="metal", override="")
 
 
 def test_gpu_plan_never_interprets():
@@ -197,3 +198,14 @@ def test_deployment_report_records_backend(tmp_path):
     rec2 = dep2.report()["nvsa"]["backend"]
     assert all(v == "xla" for v in rec2["lowerings"].values())
     assert rec2["source"] == "override:xla"
+
+
+def test_nested_recorders_survive_each_other():
+    """An inner scope whose log equals the outer's (both empty here) must
+    remove itself, not the enclosing recorder."""
+    plan = registry.negotiate(platform="cpu", override="")
+    with registry.record_selections() as outer:
+        with registry.record_selections() as inner:
+            pass
+        plan.select("qmatmul")
+    assert inner == [] and outer == [("qmatmul", "interpret")]

@@ -8,6 +8,7 @@ import time
 import jax
 import numpy as np
 import pytest
+from _numerics import assert_logprobs_close
 
 from repro.configs import base as cbase
 from repro.models import nvsa
@@ -160,11 +161,13 @@ def test_admission_full_deadline_flush_and_buckets():
     # full group dispatched immediately on the closing arrival
     full = [l for l in rep.latencies if l.close_reason == "full"]
     assert max(l.queue_s for l in full) <= 0.004 + 1e-6
-    # answers match the offline engine run bit-exactly
+    # answers match the offline engine run exactly, logprobs to the
+    # cross-batch ulp bound (the offline run batches differently)
     offline = eng.run(_oracle_requests(cfg, 9), variant="oracle")
     for uid, res in rep.results["nvsa"].items():
-        np.testing.assert_array_equal(res.answer_logprobs,
-                                      offline[uid].answer_logprobs)
+        assert res.answer == offline[uid].answer
+        assert_logprobs_close(res.answer_logprobs,
+                              offline[uid].answer_logprobs)
 
 
 def test_frontdoor_multiplexes_models():

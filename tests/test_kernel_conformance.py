@@ -204,6 +204,19 @@ def test_qmatmul_int4_edge_nibbles_exact():
     np.testing.assert_array_equal(np.asarray(out_r), exact.astype(np.float32))
 
 
+def test_qmatmul_int4_one_packed_column_over_k_steps():
+    """n <= 2 packs to a 1-column block; with k > bk the accumulation runs
+    over several K steps (the shape XLA:CPU once mis-compiled)."""
+    rng = np.random.default_rng(1)
+    x = rng.integers(-128, 128, (4, 9)).astype(np.int8)
+    w = rng.integers(-8, 8, (9, 2)).astype(np.int8)
+    exact = x.astype(np.int32) @ w.astype(np.int32)
+    out = qk.qmatmul(jnp.asarray(x), qops.pack_int4(jnp.asarray(w)),
+                     jnp.ones((4,)), jnp.ones((2,)), int4=True,
+                     interpret=True, bm=8, bn=8, bk=8)
+    np.testing.assert_array_equal(np.asarray(out), exact.astype(np.float32))
+
+
 def test_qmatmul_int8_full_range_exact():
     """int8 extremes (incl. -128) accumulate exactly in int32."""
     rng = np.random.default_rng(0)

@@ -65,3 +65,21 @@ def test_shape_bytes_parser():
     assert rl._shape_bytes("f32[4,4]") == 64
     assert rl._shape_bytes("bf16[2,3] , s8[10]") == 12 + 10
     assert rl._shape_bytes("pred[]") == 1  # scalar: empty dims
+
+
+def test_device_peaks_by_kind():
+    """The mesh search reads peaks by device_kind: a CPU host plans
+    against the modelled v5e, a known chip gets its own row, and an
+    unknown accelerator raises instead of borrowing v5e numbers."""
+    import types
+
+    import pytest
+
+    from repro.launch.mesh import HW, PEAKS, device_peaks
+
+    assert device_peaks() is HW  # the CPU test host
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peaks(v5e) is PEAKS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="TPU v9"):
+        device_peaks(types.SimpleNamespace(platform="tpu",
+                                           device_kind="TPU v9"))

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _numerics import assert_logprobs_close
 from _hypothesis_compat import given, settings, st
 
 from repro.data import raven
@@ -273,12 +274,12 @@ def test_served_answer_independent_of_admission_group():
     ("lvrf", "oracle")])
 def test_served_answer_bitwise_invariant_across_buckets(model, variant):
     """Shape-bucketing regression (extends the PR 3 admission-group
-    independence test): a request's served answer must be BIT-identical
+    independence test): a request's served answer must be identical, and
+    its logprobs within the cross-batch ulp bound (``_numerics``),
     whether it arrives in a full batch, a padded partial batch, or any
     compiled bucket size >= 2 — for every registered workload.  (Bucket 1
-    is excluded from the default ladder precisely because XLA's
-    degenerate-batch lowerings break bit-equality; see
-    frontdoor.pow2_buckets.)"""
+    is excluded from the default ladder because XLA's degenerate-batch
+    lowerings move results further; see frontdoor.pow2_buckets.)"""
     from repro.configs import base as cbase
 
     entry = cbase.REASON_WORKLOADS[model]
@@ -302,13 +303,13 @@ def test_served_answer_bitwise_invariant_across_buckets(model, variant):
     assert len({r.batch for r in bucketed.values()}) == 2  # two groups
 
     for uid in range(5):
-        np.testing.assert_array_equal(
-            full[uid].answer_logprobs, bucketed[uid].answer_logprobs,
+        assert_logprobs_close(
+            bucketed[uid].answer_logprobs, full[uid].answer_logprobs,
             err_msg=f"{model}/{variant} uid {uid} full-vs-bucketed")
         assert np.array_equal(full[uid].answer, bucketed[uid].answer)
     for uid in range(3):
-        np.testing.assert_array_equal(
-            full[uid].answer_logprobs, partial[uid].answer_logprobs,
+        assert_logprobs_close(
+            partial[uid].answer_logprobs, full[uid].answer_logprobs,
             err_msg=f"{model}/{variant} uid {uid} full-vs-padded-partial")
         assert np.array_equal(full[uid].answer, partial[uid].answer)
 

@@ -86,7 +86,7 @@ def test_least_inflight_routing_spreads_groups():
     reqs = _oracle_requests(cfg, 12)
     recs = [pool.submit(reqs[i:i + 4]) for i in (0, 4, 8)]
     # back-to-back submits with nothing drained round-robin across idle
-    # replicas (ties break to the lowest index)
+    # replicas (ties break round-robin)
     assert [r.replica for r in recs] == [0, 1, 2]
     assert pool.inflight == 3
     results = pool.drain_all()
@@ -191,3 +191,69 @@ def test_launcher_mesh_flags_name_the_escape_hatch():
         _require_devices(n, "--replicas")
     with pytest.raises(SystemExit, match="--tp"):
         _require_devices(n, "--tp")
+
+
+# -- placement: each replica's arrays on its own device ----------------------
+
+PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from repro.configs import ARCHS
+from repro.configs import base as cbase
+from repro.models import nvsa
+from repro.serve.engine import Request, ServeConfig
+from repro.serve.reason import ReasonConfig, ReasonRequest
+
+devs = jax.devices()
+assert len(devs) == 4
+
+
+def on(tree):
+    return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+
+cfg = cbase.REASON_WORKLOADS["nvsa"].make_config(d=64)
+consts = {"params": None,
+          "books": nvsa.nvsa_codebooks(cfg, jax.random.PRNGKey(1))}
+pool = cbase.reason_engine_pool(
+    "nvsa", cfg, ReasonConfig(batch_size=2, schedule="overlap"),
+    consts=consts, variants=("oracle",), replicas=4)
+attrs = np.zeros((8, cfg.raven.n_attrs), np.int32)
+group = [ReasonRequest(uid=i, context_attrs=attrs, candidate_attrs=attrs)
+         for i in range(2)]
+for i, eng in enumerate(pool.replicas):
+    sched = eng.schedules["oracle"]
+    staged, _ = eng._stage(group, sched)
+    assert on(eng.consts) == on(staged) == {devs[i]}, i
+    out = staged
+    for fn in sched.jit_stages:
+        out = fn(eng.consts, out)
+    assert on(out) == {devs[i]}, i
+
+lm, _ = cbase.lm_engine_pool(
+    "stablelm-3b", ARCHS["stablelm-3b"].make_smoke(),
+    ServeConfig(max_new_tokens=4, max_slots=2, max_len=32), replicas=4)
+lm.run([Request(uid=i, prompt=np.arange(5, dtype=np.int32) + i)
+        for i in range(8)])
+for i, eng in enumerate(lm.replicas):
+    assert on(eng.params) == on(eng._caches) == {devs[i]}, i
+print("PLACEMENT_OK")
+"""
+
+
+def test_replica_arrays_live_on_their_own_device_subprocess():
+    """Four replicas on four (virtual) devices: consts, staged inputs,
+    stage outputs, LM params and KV caches all sit on the replica's own
+    device — no group routes through device 0."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert "PLACEMENT_OK" in r.stdout, \
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"

@@ -17,6 +17,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 import jax
+from repro.configs import ARCHS
 from repro.configs import base as cbase
 from repro.serve.engine import Request, ServeConfig
 
@@ -26,7 +27,7 @@ assert jax.device_count() == 4
 def toks(arch, tp):
     scfg = ServeConfig(max_new_tokens=8, max_slots=2, max_len=64,
                        decode_block=4)
-    eng, cfg = cbase.lm_engine(arch, scfg, tp=tp)
+    eng, cfg = cbase.lm_engine(arch, ARCHS[arch].make_smoke(), scfg, tp=tp)
     rng = np.random.default_rng(0)
     reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab, (12,))
                     .astype(np.int32)) for i in range(4)]
@@ -56,7 +57,7 @@ print("llama3.2-3b tp4: fallback-sharded token stream identical")
 
 # tp beyond the device pool fails with the escape hatch in the message
 try:
-    cbase.lm_engine("stablelm-3b", tp=8)
+    cbase.lm_engine("stablelm-3b", ARCHS["stablelm-3b"].make_smoke(), tp=8)
 except ValueError as e:
     assert "xla_force_host_platform_device_count" in str(e), e
 else:
