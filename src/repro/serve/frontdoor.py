@@ -25,9 +25,11 @@ EngineProtocol` engine can serve well:
   keeps its own in-flight window on the shared host.
 - **per-request latency accounting**: arrival -> dispatch (queueing) and
   dispatch -> answers-on-host (service) per request, with p50/p95/p99
-  summaries (:meth:`FrontDoorReport.percentiles`) and per-class
-  throughput in each class's own unit (tokens/s for LM rows, problems/s
-  for NSAI rows — see :meth:`FrontDoorReport.work_per_s`).
+  summaries (:meth:`FrontDoorReport.percentiles`), the queueing split
+  by the loop's layer spans into lateness, batching wait and staging
+  (:class:`RequestLatency`), and per-class throughput in each class's
+  own unit (tokens/s for LM rows, problems/s for NSAI rows — see
+  :meth:`FrontDoorReport.work_per_s`).
 
 The serve loop is single-threaded and event-driven: it admits due
 arrivals, closes groups by the policy, dispatches them asynchronously
@@ -206,7 +208,13 @@ class RequestLatency:
 
     ``queue_s`` = arrival -> first work dispatched (admission wait + any
     blocking on the in-flight window / slot pool); ``service_s`` =
-    dispatch -> answers materialized on the host."""
+    dispatch -> answers materialized on the host.  The front-door's spans
+    split the queueing three ways, summing to ``queue_s``: ``late_s``
+    (arrival -> the start of the ``frontdoor.admit`` span that took it off
+    the stream, ``admit_s``), ``batch_s`` (-> the start of its group's
+    ``frontdoor.close`` span, ``close_s``) and ``staging_s`` (-> the end
+    of the engine's ``reason.stage`` span, ``dispatch_s``: ingest, stack
+    and copy to the device)."""
 
     uid: int
     model: str
@@ -214,13 +222,26 @@ class RequestLatency:
     dispatch_s: float
     done_s: float
     bucket: int
-    group_size: int
     close_reason: str             # full | deadline | flush
     priority: str = DEFAULT_PRIORITY
+    admit_s: float | None = None
+    close_s: float | None = None
 
     @property
     def queue_s(self) -> float:
         return self.dispatch_s - self.arrival_s
+
+    @property
+    def late_s(self) -> float:
+        return self.admit_s - self.arrival_s
+
+    @property
+    def batch_s(self) -> float:
+        return self.close_s - self.admit_s
+
+    @property
+    def staging_s(self) -> float:
+        return self.dispatch_s - self.close_s
 
     @property
     def service_s(self) -> float:
@@ -233,20 +254,26 @@ class RequestLatency:
 
 @dataclasses.dataclass
 class ServedGroup:
-    """One admission group as the front-door closed and served it."""
+    """One admission group as the front-door closed and served it.
+
+    ``enqueue_s`` / ``wait_s`` / ``collect_s`` are the engine's
+    :class:`~repro.serve.runtime.GroupRecord` service split, carried
+    through (0 for engines without those spans)."""
 
     model: str
     uids: tuple[int, ...]
     bucket: int
     size: int
     close_reason: str
-    open_s: float                 # arrival of the group's first request
     close_s: float                # when the admission policy closed it
     dispatch_s: float
     done_s: float
     # which replica of a ReplicaPool served the group (None = unpooled
     # engine); read off the engine's GroupRecord stamp
     replica: int | None = None
+    enqueue_s: float = 0.0
+    wait_s: float = 0.0
+    collect_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -520,10 +547,11 @@ class FrontDoor:
             {m: (ctl.queues(m) if ctl is not None else ClassQueues())
              for m in self.engines}
         shed: list[ShedRecord] = []
+        # uid -> when the loop took it off the stream, per model; also the
         # serve-lifetime duplicate guard: engines intentionally allow uid
         # reuse after a drain, so a duplicate that slips past a mid-serve
         # drain would silently overwrite the earlier answer in `results`
-        seen: dict[str, set] = {m: set() for m in self.engines}
+        admitted: dict[str, dict[int, float]] = {m: {} for m in self.engines}
         # (model, rec, close_reason, close_s, [arrival times], [classes])
         submitted: list[tuple[str, GroupRecord, str, float,
                               list[float], list[str]]] = []
@@ -537,8 +565,12 @@ class FrontDoor:
 
         def close_group(model: str, reason: str):
             group = pending[model].pop(self._cap(model))
-            rec = self.engines[model].submit([a.request for a in group])
-            entry = (model, rec, reason, now(), [a.t for a in group],
+            # closed when submit is called, not when it returns (under a
+            # synchronous schedule that would be the group's done time)
+            with rt.Span("frontdoor.close", self._clock) as span:
+                rec = self.engines[model].submit([a.request for a in group])
+                span.annotate(group=rec.index)
+            entry = (model, rec, reason, span.start - t0, [a.t for a in group],
                      [a.priority or DEFAULT_PRIORITY for a in group])
             submitted.append(entry)
             if ctl is not None:
@@ -565,10 +597,17 @@ class FrontDoor:
         it = iter(arrivals)
         nxt = next(it, None)
         last_t = -float("inf")
-        while True:
-            t = now()
-            # admit every due arrival (pulling the iterator renders the
-            # request — ingest work happens inside the serving loop)
+
+        def full(model: str) -> bool:
+            return len(pending[model]) >= self._cap(model) \
+                and self._accepting(model)
+
+        def admit(t: float, at: float) -> str | None:
+            # admit arrivals due by t, taken off the stream at `at` (pulling
+            # the iterator renders the request — ingest work happens inside
+            # the serving loop) until one fills its model's group; that
+            # model is returned
+            nonlocal nxt, last_t
             while nxt is not None and nxt.t <= t:
                 if nxt.model not in self.engines:
                     raise ValueError(f"arrival for unknown model "
@@ -581,19 +620,29 @@ class FrontDoor:
                 last_t = nxt.t
                 model = nxt.model
                 uid = nxt.request.uid
-                if uid in seen[model]:
+                if uid in admitted[model]:
                     raise ValueError(f"duplicate request uid {uid} for "
                                      f"model {model!r} (results are keyed "
                                      "by uid)")
-                seen[model].add(uid)
+                admitted[model][uid] = at
                 prio = nxt.priority or rt.request_priority(nxt.request)
                 arrival = dataclasses.replace(nxt, priority=prio)
-                rejected = pending[model].offer(arrival, prio, now())
+                rejected = pending[model].offer(arrival, prio, at)
                 if rejected is not None:
                     shed.append(rejected)
                 nxt = next(it, None)
-                while len(pending[model]) >= self._cap(model) \
-                        and self._accepting(model):
+                if full(model):
+                    return model
+            return None
+
+        while True:
+            t = now()
+            # the admit spans leave out the closes, so the two never nest;
+            # a span's start stamps every arrival it admits
+            while nxt is not None and nxt.t <= t:
+                with rt.Span("frontdoor.admit", self._clock) as span:
+                    model = admit(t, span.start - t0)
+                while model is not None and full(model):
                     close_group(model, "full")
             if nxt is None:
                 # stream over: no future arrival can fill an open group,
@@ -635,18 +684,20 @@ class FrontDoor:
                 # the device keeps working while the host waits; collect
                 # whatever finished so done-stamps aren't deferred, and
                 # let host-pumped engines (LM decode) advance a block
-                inflight = 0
-                for model, eng in self.engines.items():
-                    results[model].update(eng.drain_ready())
-                    inflight += eng.inflight
-                self._sleep(min(dt, self.cfg.poll_s) if inflight else dt)
+                with rt.Span("frontdoor.poll", self._clock):
+                    inflight = 0
+                    for model, eng in self.engines.items():
+                        results[model].update(eng.drain_ready())
+                        inflight += eng.inflight
+                    self._sleep(min(dt, self.cfg.poll_s) if inflight else dt)
             elif deferred:
                 # every pending event is past due but the engines are
                 # backpressuring: drain to free window room and let time
                 # advance one poll, or a virtual clock would livelock
-                for model, eng in self.engines.items():
-                    results[model].update(eng.drain_ready())
-                self._sleep(self.cfg.poll_s)
+                with rt.Span("frontdoor.poll", self._clock):
+                    for model, eng in self.engines.items():
+                        results[model].update(eng.drain_ready())
+                    self._sleep(self.cfg.poll_s)
 
         for model, eng in self.engines.items():
             results[model].update(eng.drain_all())
@@ -666,14 +717,15 @@ class FrontDoor:
             done_s = rec.done_t - t0
             groups.append(ServedGroup(
                 model=model, uids=rec.uids, bucket=rec.bucket, size=rec.size,
-                close_reason=reason, open_s=min(arr_times), close_s=close_s,
-                dispatch_s=dispatch_s, done_s=done_s, replica=rec.replica))
+                close_reason=reason, close_s=close_s, dispatch_s=dispatch_s,
+                done_s=done_s, replica=rec.replica, enqueue_s=rec.enqueue_s,
+                wait_s=rec.wait_s, collect_s=rec.collect_s))
             for uid, arr, prio in zip(rec.uids, arr_times, prios):
                 latencies.append(RequestLatency(
                     uid=uid, model=model, arrival_s=arr,
                     dispatch_s=dispatch_s, done_s=done_s, bucket=rec.bucket,
-                    group_size=rec.size, close_reason=reason,
-                    priority=prio))
+                    close_reason=reason, priority=prio,
+                    admit_s=admitted[model][uid], close_s=close_s))
         return FrontDoorReport(
             results=results, latencies=latencies, groups=groups,
             wall_time_s=wall, shed=shed,

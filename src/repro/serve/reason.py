@@ -175,11 +175,14 @@ class ReasonEngine:
     EngineProtocol` (``configs.base.reason_engine`` binds it for you).
     ``run(requests)`` feeds every request batch through the schedule's
     stages.  ``clock`` is the timestamp source for
-    :class:`~repro.serve.runtime.GroupRecord`\\ s (the front-door injects
-    its own so queue/service latencies share one origin); ``wall`` is the
-    real wall-clock the throughput accounting reads — separate so a
-    virtual front-door clock never distorts measured rates, injectable so
-    the accounting itself is testable.  ``device`` is where staged inputs
+    :class:`~repro.serve.runtime.GroupRecord`\\ s and the engine's layer
+    spans (the front-door injects its own so queue/service latencies share
+    one origin), and so also for the per-stage times in
+    ``stats["stage_time_s"]``, which sum the ``reason.enqueue`` /
+    ``reason.wait`` spans; ``wall`` is the real wall-clock the throughput
+    accounting reads — separate so a virtual front-door clock never
+    distorts measured rates, injectable so the accounting itself is
+    testable.  ``device`` is where staged inputs
     land (None = the default device): a replica passes the device its
     ``consts`` live on, so its groups never route through device 0.
     """
@@ -275,7 +278,8 @@ class ReasonEngine:
 
     def _collect(self, batch: list[ReasonRequest], out,
                  rec: GroupRecord, sched: StagedSchedule,
-                 cold: bool = False, t0: float | None = None):
+                 cold: bool = False, t0: float | None = None,
+                 blocked: bool = False):
         """Materialize one group's answers on the host (blocks if pending).
 
         Finished results land in the engine's ready buffer until a drain
@@ -285,12 +289,23 @@ class ReasonEngine:
         per-group busy windows ([dispatch, collect] on the real clock,
         clipped so overlapping windows are not double-counted), so
         ``problems_per_s()`` reports a real measured rate for engines that
-        never see ``run()``."""
-        host = jax.tree.map(np.asarray, out)
-        for i, req in enumerate(batch):  # padded rows have no request
-            fields = sched.collect(host, i)
-            self._ready[req.uid] = ReasonResult(uid=req.uid, batch=rec.index,
-                                                **fields)
+        never see ``run()``.
+
+        Unless the caller already ``blocked`` on the outputs (``sequential``
+        waits on every stage), the block on them is its own ``reason.wait``
+        span, ahead of the copy (``reason.collect``), so a wait under
+        ``overlap`` / ``fused`` is not hidden in ``np.asarray``."""
+        if not blocked:
+            with rt.Span("reason.wait", self.clock, rec, "wait_s",
+                         group=rec.index):
+                jax.block_until_ready(out)
+        with rt.Span("reason.collect", self.clock, rec, "collect_s",
+                     group=rec.index):
+            host = jax.tree.map(np.asarray, out)
+            for i, req in enumerate(batch):  # padded rows have no request
+                fields = sched.collect(host, i)
+                self._ready[req.uid] = ReasonResult(
+                    uid=req.uid, batch=rec.index, **fields)
         rec.done_t = self.clock()
         self.stats["requests"] += len(batch)
         if not self._in_run and t0 is not None:
@@ -359,7 +374,9 @@ class ReasonEngine:
                 raise ValueError(f"duplicate request uid {req.uid} "
                                  "(results are keyed by uid)")
             seen.add(req.uid)
-        bufs, bucket = self._stage(group, sched)
+        index = self._next_index
+        with rt.Span("reason.stage", self.clock, group=index) as staging:
+            bufs, bucket = self._stage(group, sched)
         use_fused = False
         if schedule == "fused":
             if sched.fused_ok:
@@ -374,35 +391,42 @@ class ReasonEngine:
         if cold:
             self._warmed.add((variant, bucket, mode))
             self._cold_run = True
-        rec = GroupRecord(uids=tuple(r.uid for r in group),
-                          index=self._next_index, variant=variant,
-                          bucket=bucket, size=len(group))
+        rec = GroupRecord(uids=tuple(r.uid for r in group), index=index,
+                          variant=variant, bucket=bucket, size=len(group))
         self._next_index += 1
         stage_time = self.stats["stage_time_s"].setdefault(variant, {})
         t0 = self.wall()
         # dispatch the whole pipeline asynchronously FIRST; any blocking
         # (sequential timing, window trimming) happens after, so group i+1
-        # is always on the device before the engine waits on group i
-        rec.dispatch_t = self.clock()
+        # is always on the device before the engine waits on group i.
+        # Queueing ends where staging does: the rest is service
+        rec.dispatch_t = staging.end
         if use_fused:
-            bufs = sched.jit_fused(consts, bufs)
+            with rt.Span("reason.enqueue", self.clock, rec, "enqueue_s",
+                         group=index, stage="fused"):
+                bufs = sched.jit_fused(consts, bufs)
             self.stats["dispatches"] += 1
             self.stats["fused_groups"] += 1
         else:
-            for si, fn in enumerate(sched.jit_stages):
-                ts = self.wall()
-                bufs = fn(consts, bufs)
+            for spec, fn in zip(sched.stages, sched.jit_stages):
+                with rt.Span("reason.enqueue", self.clock, rec, "enqueue_s",
+                             group=index, stage=spec.name) as enqueue:
+                    bufs = fn(consts, bufs)
                 self.stats["dispatches"] += 1
                 if sequential:
-                    jax.block_until_ready(bufs)
-                    name = sched.stages[si].name
-                    dt = self.wall() - ts
-                    stage_time[name] = stage_time.get(name, 0.0) + dt
-                    self._run_stage_time[name] = \
-                        self._run_stage_time.get(name, 0.0) + dt
+                    # a stage's time is its dispatch plus the block on it
+                    with rt.Span("reason.wait", self.clock, rec, "wait_s",
+                                 group=index, stage=spec.name) as wait:
+                        jax.block_until_ready(bufs)
+                    dt = enqueue.elapsed + wait.elapsed
+                    stage_time[spec.name] = \
+                        stage_time.get(spec.name, 0.0) + dt
+                    self._run_stage_time[spec.name] = \
+                        self._run_stage_time.get(spec.name, 0.0) + dt
         self.stats["batches"] += 1
         if sequential:
-            self._collect(group, bufs, rec, sched, cold=cold, t0=t0)
+            self._collect(group, bufs, rec, sched, cold=cold, t0=t0,
+                          blocked=True)
         else:
             self._inflight.append((group, bufs, rec, sched, cold, t0))
             # window backpressure: trim back down to max_inflight by

@@ -28,6 +28,14 @@ is the single runtime surface both engines now implement natively:
   front-door points every engine at one clock so queue/service latencies
   share an origin.
 
+Layer spans (:class:`Span`) mark the serving path's boundaries —
+``frontdoor.admit`` / ``frontdoor.close`` / ``frontdoor.poll`` in the
+front-door, ``reason.stage`` / ``reason.enqueue`` / ``reason.wait`` /
+``reason.collect`` in the NSAI engine.  Each is recorded from one call
+site on the record the front-door returns (a stamp or a summed field, on
+the injected clock) and in the profiler's trace, as an annotation of the
+same name carrying ``group=<GroupRecord.index>``.
+
 The *request/result envelope* is structural, not nominal: any request
 object with a ``uid`` (``serve.engine.Request``, ``serve.reason.
 ReasonRequest``) and any result with a ``uid`` plus its payload
@@ -50,6 +58,8 @@ import dataclasses
 from typing import Any, Callable, Iterable, Mapping, Protocol, Sequence, \
     runtime_checkable
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 
 @dataclasses.dataclass
 class GroupRecord:
@@ -63,6 +73,12 @@ class GroupRecord:
     dispatch -> done is service.  ``bucket`` is the compiled batch shape
     the group ran at (NSAI: the covering batch bucket; LM: the slot-pool
     width the decode batch is compiled for).
+
+    ``enqueue_s`` / ``wait_s`` / ``collect_s`` split the service time by
+    layer span (engine clock, summed over the group's spans): the host's
+    dispatch of each stage (``reason.enqueue``), the host blocked on the
+    device (``reason.wait``) and the copy back and unpack
+    (``reason.collect``).  Engines without those spans leave them 0.
     """
 
     uids: tuple[int, ...]
@@ -75,6 +91,55 @@ class GroupRecord:
     # which replica of a ReplicaPool served the group (None = the engine
     # is not pooled); stamped by ``serve.replica.ReplicaPool.submit``
     replica: int | None = None
+    enqueue_s: float = 0.0
+    wait_s: float = 0.0
+    collect_s: float = 0.0
+
+
+class Span:
+    """One layer span of the serving path, recorded from one call site in
+    two places: on a record, and in the profiler's trace.
+
+    On entry it opens a ``jax.profiler.TraceAnnotation`` named ``name``
+    with ``args`` as its stats (``group=<GroupRecord.index>``,
+    ``stage=...``), so a traced window shows the span on the ``/host:CPU``
+    plane on the device trace's clock, and reads ``clock`` (``start``, a
+    stamp).  On exit it reads ``clock`` again (``end``, a stamp, and
+    ``elapsed``) and, given a ``record``, adds ``elapsed`` to its
+    ``field``.  ``annotate`` adds stats known only inside the span (the
+    front-door learns a group's index from ``submit``).  With the profiler
+    off no annotation is made: a span then costs its two clock reads and
+    the ``with`` statement.
+    """
+
+    __slots__ = ("name", "clock", "record", "field", "args", "start", "end",
+                 "elapsed", "_ann")
+
+    def __init__(self, name: str, clock: Callable[[], float],
+                 record: Any = None, field: str | None = None, **args):
+        self.name, self.clock, self.args = name, clock, args
+        self.record, self.field = record, field
+
+    def __enter__(self) -> "Span":
+        self._ann = None
+        if _Annotation.is_enabled():
+            self._ann = _Annotation(self.name, **self.args)
+            self._ann.__enter__()
+        self.start = self.clock()
+        return self
+
+    def annotate(self, **args) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        self.end = self.clock()
+        self.elapsed = self.end - self.start
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.record is not None:
+            setattr(self.record, self.field,
+                    getattr(self.record, self.field) + self.elapsed)
 
 
 @runtime_checkable

@@ -86,7 +86,7 @@ def test_attainment_exact_counts():
     lats = [fd.RequestLatency(uid=i, model="m", arrival_s=0.0,
                               dispatch_s=0.0,
                               done_s=0.01 if i < 9 else 1.0, bucket=1,
-                              group_size=1, close_reason="full",
+                              close_reason="full",
                               priority="interactive")
             for i in range(10)]
     att = slo_mod.attainment(lats, targets)
