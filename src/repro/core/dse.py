@@ -215,7 +215,7 @@ class ServingPlan:
     batch_size: int               # admission-group ceiling
     buckets: tuple[int, ...]      # compiled batch-size buckets, ascending
     max_inflight: int             # depth of the pipelined in-flight window
-    schedule: str                 # overlap | sequential (ReasonConfig knob)
+    schedule: str                 # ReasonConfig knob: always overlap
     design: DesignConfig          # the DSE point the knobs derive from
 
 
@@ -223,10 +223,14 @@ def serving_plan(design: DesignConfig, max_batch: int = 8,
                  inflight_cap: int = 4, min_bucket: int = 2) -> ServingPlan:
     """Map an explored design point onto the serving runtime's knobs.
 
-    - **schedule**: Algorithm 1's mode decision carries over directly —
-      a ``parallel`` design (concurrent nn/vsa streams win analytically)
-      serves with the ``overlap`` pipelined schedule; a ``sequential``
-      design (unfolded array wins) serves with the synchronous schedule.
+    - **schedule**: every design serves with the ``overlap`` pipelined
+      schedule, so the host never blocks on a group stage by stage.
+      Algorithm 1's folded-vs-unfolded decision partitions an FPGA array;
+      on a device with an asynchronous queue a group's jitted stages run
+      in order whatever the host does, and blocking after each adds host
+      round trips and changes nothing on the device.  The mode still sets
+      the window depth below.  ``deploy()`` upgrades ``overlap`` to
+      ``fused`` where the fused negotiation is exact.
     - **batch buckets**: the admission width maps requests across the
       ``N`` sub-arrays, so the group ceiling is the largest power of two
       <= N (clamped to [min_bucket, max_batch]); the covering-bucket
@@ -236,8 +240,9 @@ def serving_plan(design: DesignConfig, max_batch: int = 8,
     - **max_inflight**: the in-flight window depth is the analytical
       folded-vs-unfolded gain ``t_seq / t_para`` rounded (clamped to
       [1, inflight_cap]) — the deeper the array's concurrency win, the
-      more groups the host keeps resident; a sequential design pipelines
-      nothing (depth 1).
+      more groups the host keeps resident; a sequential design keeps
+      depth 1, where ``submit`` still dispatches group *i* before it
+      blocks on group *i-1*.
     """
     # lazy import: serve.frontdoor is jax-free and does not import core,
     # so borrowing its bucket ladder keeps one source of bucket policy
@@ -246,19 +251,18 @@ def serving_plan(design: DesignConfig, max_batch: int = 8,
     if max_batch < 1 or min_bucket < 1:
         raise ValueError("max_batch and min_bucket must be >= 1")
     min_bucket = min(min_bucket, max_batch)
-    schedule = "overlap" if design.mode == "parallel" else "sequential"
     batch = 1
     while batch * 2 <= max(1, design.N):
         batch *= 2
     batch = max(min_bucket, min(max_batch, batch))
     buckets = pow2_buckets(batch, min_bucket=min_bucket)
-    if schedule == "sequential":
+    if design.mode == "sequential":
         depth = 1
     else:
         depth = max(1, min(inflight_cap,
                            round(design.t_seq / max(1, design.t_para))))
     return ServingPlan(batch_size=batch, buckets=buckets, max_inflight=depth,
-                       schedule=schedule, design=design)
+                       schedule="overlap", design=design)
 
 
 # ---------------------------------------------------------------------------

@@ -13,9 +13,11 @@ actual serving path:
    under the deployment :class:`Budget` (PE count), picking the AdArray
    shape, mode, and static nn/vsa partition.
 3. **derive** — ``core.dse.serving_plan`` maps the winning design point
-   onto the serving runtime's knobs (batch buckets, ``max_inflight``,
-   overlap-vs-sequential schedule), and the engines are compiled from the
-   *plan* instead of hand-set ``ReasonConfig`` fields.
+   onto the serving runtime's knobs (batch buckets, ``max_inflight``),
+   and the engines are compiled from the *plan* instead of hand-set
+   ``ReasonConfig`` fields.  Every plan serves asynchronously: the
+   ``overlap`` schedule, upgraded to one ``fused`` dispatch per group
+   where the fused negotiation is exact.
 
 LM workloads (token-in/token-out archs) have a single homogeneous nn
 stream — the dual-stream AdArray DSE has nothing to partition — so their
@@ -507,14 +509,13 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
                 fused=True if schedule == "fused" else "auto")
             # fused-pipeline negotiation: when the compiled schedule's
             # fused variant is provably bit-identical under the deployment
-            # plan, serve one dispatch per admission group instead of K
-            # (the engine still falls back per-stage if the schedule's
-            # negotiation says epsilon — answers never change).  Replicas
-            # share one compiled schedule but carry their own cfg copy,
-            # so the upgrade applies per replica.
+            # plan, serve one dispatch per admission group instead of K;
+            # where it is only epsilon-equivalent the plan's ``overlap``
+            # stands and dispatches stage by stage (answers never change).
+            # Replicas share one compiled schedule but carry their own cfg
+            # copy, so the upgrade applies per replica.
             subs = eng.replicas if hasattr(eng, "replicas") else [eng]
-            if schedule is None and plan.schedule == "overlap" and \
-                    subs[0].schedules[variant].fused_ok:
+            if schedule is None and subs[0].schedules[variant].fused_ok:
                 for sub in subs:
                     sub.cfg.schedule = "fused"
             classes[m], designs[m], plans[m] = "reason", design, plan

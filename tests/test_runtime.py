@@ -88,11 +88,17 @@ def test_serving_plan_maps_design_to_knobs():
                        nl_bar=3, nv_bar=3, t_para=100, t_seq=90,
                        t_phase1=90)
     plan = serving_plan(seq, max_batch=8)
-    assert plan.schedule == "sequential" and plan.max_inflight == 1
+    # the host never blocks per stage: an unfolded (sequential) design
+    # serves the pipelined schedule with a one-group window
+    assert plan.schedule == "overlap" and plan.max_inflight == 1
     assert plan.batch_size == 2 and plan.buckets == (2,)  # pow2 floor of 3
     # the inflight cap binds
     deep = serving_plan(para, max_batch=4, inflight_cap=1)
     assert deep.max_inflight == 1 and deep.batch_size == 4
+    # no design maps to the host-synchronous schedule
+    assert {serving_plan(d, max_batch=b, inflight_cap=c).schedule
+            for d in (para, seq) for b in (1, 2, 8) for c in (1, 4)} \
+        == {"overlap"}
 
 
 def test_deploy_selects_serving_config_from_dse(monkeypatch):
