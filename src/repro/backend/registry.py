@@ -29,7 +29,7 @@ This registry makes lowering selection one explicit layer:
   capabilities (block dim ``d``), and the plan picks the first feasible
   lowering in the chain — so a non-pow2 ``d`` degrades past the compiled
   Pallas lowering (whose Mosaic tiling is only validated on pow2 block
-  dims >= 8) instead of crashing the circulant builder; the interpreter
+  dims >= 8) instead of failing in Mosaic; the interpreter
   serves any shape, bit-for-bit with the kernel semantics.
 
 ``serve.schedule.compile_schedule`` scopes every compiled stage to a plan
@@ -66,7 +66,7 @@ class Lowering:
 
     Capability predicates: ``platforms`` gates *negotiation* (which
     platforms may prefer this lowering); ``requires_pow2`` / ``min_size``
-    gate *call sites* (the Pallas circulant builder needs a power-of-two
+    gate *call sites* (the compiled Pallas kernels need a power-of-two
     block dim).  ``equivalence`` declares the conformance class versus the
     kernel's ``xla`` reference: ``exact`` means bit-identical outputs,
     ``epsilon`` means agreement within ``epsilon`` — the tolerance
@@ -153,8 +153,9 @@ def _pallas_family(kernel: str, *, epsilon: float, requires_pow2=False,
     accelerators.  The interpreter executes the same kernel semantics in
     plain XLA and is conformant at any shape — the registry's earlier
     claim that the circulant builder itself needs pow2 was disproven by
-    the kernel-vs-registry consistency check (NSF006): interpret output is
-    bit-identical to the gather reference at non-pow2 / small block dims.
+    the kernel-vs-registry consistency check (NSF006): interpret output
+    matches the gather reference within epsilon at non-pow2 / small block
+    dims.
     """
     return (
         Lowering(kernel=kernel, name="pallas", platforms=("tpu", "gpu"),
@@ -170,7 +171,8 @@ KERNELS: dict[str, KernelSpec] = {
     "circ_conv": KernelSpec(
         name="circ_conv",
         describe="blockwise circular conv/corr (VSA bind/unbind) via the "
-                 "circulant-matmul Pallas kernel",
+                 "direct-form (elem) and circulant-matmul (dict) Pallas "
+                 "kernels",
         lowerings=_pallas_family(
             "circ_conv", epsilon=1e-3, requires_pow2=True, min_size=8,
             note="Mosaic tiling validated on pow2 block dims >= 8") + (

@@ -1,34 +1,41 @@
-"""Pallas TPU kernel: blockwise circular convolution / correlation.
-
-TPU adaptation of NSFlow's AdArray passing-register streaming (Sec IV-B).
-A TPU has no per-PE register muxes, so instead of skew-streaming the second
-operand we *materialize its circulant matrix in VMEM* with log2(d)
-roll-select steps (each roll is a static concatenate — VPU-friendly), then
-feed the MXU:
-
-    conv:  C[n, k] = y[(n-k) mod d]  ->  out = x @ C^T
-    corr:  C[n, k] = y[(n+k) mod d]  ->  out = x @ C^T
-
-Both modes run corr's builder: ``conv(x, y) == corr(x_rev, y)`` with
-``x_rev[k] = x[(-k) mod d]``.  The reversal happens in the wrapper, before
-the ``pallas_call``, because Mosaic has no lowering for ``rev`` and rejects
-the forward-rolling builder's layout at d >= 256.
+"""Pallas TPU kernels: blockwise circular convolution / correlation.
 
 Two grid layouts:
-- ``elem``  — pairwise binding of N (x_i, y_i) pairs: per-row circulants,
-  batched mat-vec. Low-reuse, the "symbolic stream" of the paper.  Every
-  (pair, block) row is independent, so the wrapper flattens ``(N, B, d)``
-  to ``(N*B, d)`` rows and tiles them ``tn`` at a time.
-- ``dict``  — N queries against M static dictionary entries: one circulant
-  per dictionary entry is reused by a whole (tile_n × d) MXU matmul. This is
-  the high-reuse path the TPU rewrite unlocks.  The wrapper moves the VSA
-  block axis in front (``(B, N, d)``) so each block is a ``(tn, d)`` tile.
+
+- ``elem`` — pairwise binding of N (x_i, y_i) pairs, the low-reuse
+  "symbolic stream" of the paper.  It computes the direct form on the VPU
+  in f32, the TPU counterpart of NSFlow's AdArray passing-register
+  streaming (Sec IV-B): where the FPGA passes the second operand one
+  register per cycle, the kernel walks it one sublane per step through
+  VMEM, and each step is a broadcast multiply-accumulate.
+
+      conv:  out[r, n] = sum_k x[r, k] * y[r, (n - k) mod d]
+      corr:  out[r, n] = sum_k x[r, k] * y[r, (n + k) mod d]
+
+  A tile of ``R`` rows is turned on its side in VMEM: ``d`` along
+  sublanes, rows along lanes.  A row tile narrower than the 128 lanes is
+  repeated in ``G`` lane groups, and group ``c`` computes outputs
+  ``n`` in ``[cL, (c+1)L)``, ``L = d / G``, so every vreg is full.  Step
+  ``k`` then reads x's column ``k`` as one stride-0 sublane broadcast and
+  y's shifted window as one unaligned sublane load: no per-step lane
+  shuffles and no per-row d x d circulant.  Each output sums its ``d``
+  products into ``_PARTS`` interleaved partial sums, added as a tree at
+  the end; one running sum reads three to four times the error of the
+  gather reference at d = 256 and 512, so the order is part of the
+  kernel.
+
+- ``dict`` — N queries against M static dictionary entries: one circulant
+  per dictionary entry (log2(d) roll-select steps) is reused by a whole
+  (tile_n x d) MXU matmul.  The wrapper moves the VSA block axis in front
+  (``(B, N, d)``) so each block is a ``(tn, d)`` tile; conv runs corr's
+  builder on ``x_rev[k] = x[(-k) mod d]`` (``rev_fixed0``), reversed before
+  the ``pallas_call`` because Mosaic has no lowering for ``rev``.
 
 Mosaic tiles the last two block dims by (8, 128) unless a dim spans the
-whole array, so row tiles are multiples of 8 and ``d`` is either a multiple
-of 128 or the whole last dim.  ``d`` must be a power of two on the compiled
-path (NVSA block dims are 256/512); the registry routes other shapes to the
-XLA gather reference.
+whole array, so row tiles are multiples of 8.  ``d`` must be a power of
+two >= 8 on the compiled path (NVSA block dims are 256/512); the registry
+routes other shapes to the XLA gather reference.  Interpret mode serves
+any ``d``.
 """
 
 from __future__ import annotations
@@ -40,15 +47,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# Scoped-VMEM planning for the elem kernel: the roll-select builder keeps
-# about three (tn, d, d) f32 copies live (the circulant, its roll, the
-# select result).  Row tiles come out of _ELEM_BUDGET; a tile of 8 rows
-# that still overflows the compiler's 16 MiB default gets an explicit
-# limit, capped below the v5e core's 128 MiB.
-_LIVE_COPIES = 3
-_ELEM_BUDGET = 12 * 2 ** 20
-_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
-_MAX_VMEM = 100 * 2 ** 20
+_LANES = 128
+# Interleaved partial sums per output (the accumulation order above); output
+# sublanes per pass over k, so the partial sums hold 16 of the 64 vregs; and
+# k-steps per loop iteration on the compiled path.  Chosen on the v5e's
+# static schedule: 32 sublanes spill, and so does unrolling all d steps.
+_PARTS = 8
+_CHUNK = 16
+_UNROLL = 64
 
 
 def _circulant(base: jax.Array) -> jax.Array:
@@ -83,18 +89,6 @@ def _corr_operand(x: jax.Array, mode: str) -> jax.Array:
     return rev_fixed0(x)
 
 
-def _elem_kernel(x_ref, y_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)  # (tn, d)
-    c = _circulant(y_ref[...].astype(jnp.float32))  # (tn, d, d)
-    # out[r, n] = sum_k x[r, k] * c[r, n, k]  — batched matvec
-    out = jax.lax.dot_general(
-        c, x,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    o_ref[...] = out.astype(o_ref.dtype)
-
-
 def _dict_kernel(x_ref, y_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)  # (tn, d)
     c = _circulant(y_ref[...].astype(jnp.float32))[0]  # (d, d)
@@ -107,41 +101,120 @@ def _dict_kernel(x_ref, y_ref, o_ref):
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _elem_tile(d: int) -> int:
-    """Rows per elem tile: a multiple of 8 (Mosaic's sublane tile), at
-    least 8, such that the live circulant copies fit ``_ELEM_BUDGET``."""
-    rows = _ELEM_BUDGET // (_LIVE_COPIES * d * d * 4)
-    return max(8, min(64, rows // 8 * 8))
+def _elem_tile(rows: int, d: int) -> tuple[int, int]:
+    """(R, G): rows per tile (a multiple of 8, at most the 128 lanes) and
+    lane groups.  G is the largest power of two with G * R <= 128 lanes
+    that splits ``d`` into groups of whole 8-sublane vregs."""
+    tile = min(_LANES, -(-rows // 8) * 8)
+    groups = 1
+    while (2 * groups * tile <= _LANES and d % (2 * groups) == 0
+           and d // (2 * groups) % 8 == 0):
+        groups *= 2
+    return tile, groups
 
 
-def _elem_vmem_limit(tn: int, d: int) -> int:
-    """Scoped-VMEM limit for one elem tile: the live circulant copies plus
-    double-buffered (tn, d) x / y / out tiles, with 25% headroom."""
-    need = _LIVE_COPIES * tn * d * d * 4 + 3 * 2 * tn * d * 4
-    return min(_MAX_VMEM, max(_DEFAULT_SCOPED_VMEM, need + need // 4))
+def _tree_sum(parts: list[jax.Array]) -> jax.Array:
+    """Pairwise sum of a power-of-two number of partial sums."""
+    while len(parts) > 1:
+        parts = [a + b for a, b in zip(parts[::2], parts[1::2])]
+    return parts[0]
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "interpret", "tile_n"))
+def _elem_kernel(x_ref, y_ref, o_ref, xb_ref, z_ref, ot_ref, *,
+                 conv: bool, groups: int, unroll: int):
+    rows, d = x_ref.shape
+    span = d // groups  # outputs per lane group
+    f32 = jnp.float32
+
+    def sideways(ref):
+        """(R, d) -> (d, 128): lane c*R + r holds row r, for every group c."""
+        tile = [ref[...].astype(f32)] * groups
+        if groups * rows < _LANES:
+            tile.append(jnp.zeros((_LANES - groups * rows, d), f32))
+        return jnp.concatenate(tile, axis=0).T
+
+    xb_ref[...] = sideways(x_ref)
+    yt = sideways(y_ref)
+    yy = jnp.concatenate([yt, yt], axis=0)
+    # z[i, (c, r)] = y[r, (c*span + i) mod d]: group c's window of y, where
+    # conv finds y[n - k] at z[n - c*span + d - k] and corr y[n + k] at
+    # z[n - c*span + k].
+    width, block = z_ref.shape[0], min(64, d)
+    for i in range(0, width, block):
+        n = min(block, width - i)
+        group = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 1) // rows
+        z = yy[i % d:i % d + n]
+        for c in range(1, groups):
+            s = (c * span + i) % d
+            z = jnp.where(group == c, yy[s:s + n], z)
+        z_ref[pl.ds(i, n), :] = z
+
+    chunk = min(_CHUNK, span) if span % min(_CHUNK, span) == 0 else span
+
+    def accumulate(j, carry):
+        """Outputs m .. m+chunk-1 of every group: k = 0 .. d-1 into partial
+        sum k % _PARTS, then their tree sum."""
+        m = pl.multiple_of(j * chunk, chunk)
+
+        def steps(base, n, accs):
+            zs = z_ref.at[pl.ds(m + d + 1 - n - base if conv else m + base,
+                                chunk + n - 1)]
+            xs = xb_ref.at[pl.ds(base, n)]
+            accs = list(accs)
+            for u in range(n):
+                xk = jnp.broadcast_to(xs[pl.ds(u, 1), :], (chunk, _LANES))
+                zk = zs[pl.ds(n - 1 - u if conv else u, chunk), :]
+                accs[u % _PARTS] = accs[u % _PARTS] + xk * zk
+            return tuple(accs)
+
+        accs = (jnp.zeros((chunk, _LANES), f32),) * _PARTS
+        loops, rest = divmod(d, unroll)
+        if loops:
+            accs = jax.lax.fori_loop(
+                0, loops, lambda i, a: steps(
+                    pl.multiple_of(i * unroll, unroll), unroll, a), accs)
+        if rest:
+            accs = steps(loops * unroll, rest, accs)
+        # out[i, (c, r)] = result[r, c*span + m + i]
+        ot_ref[pl.ds(m, chunk), :] = _tree_sum(list(accs))
+        return carry
+
+    jax.lax.fori_loop(0, span // chunk, accumulate, 0)
+    t = ot_ref[...].T  # (128, span): row c*R + r holds result[r, c*span:]
+    o_ref[...] = jnp.concatenate(
+        [t[c * rows:(c + 1) * rows] for c in range(groups)],
+        axis=1).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "interpret"))
 def circ_elem(x: jax.Array, y: jax.Array, *, mode: str = "conv",
-              interpret: bool, tile_n: int | None = None) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Pairwise binding. x, y: (N, B, d) -> (N, B, d)."""
+    if mode not in ("conv", "corr"):
+        raise ValueError(f"mode must be 'conv' or 'corr', got {mode!r}")
     n, b, d = x.shape
-    x = _corr_operand(x, mode)
     rows = n * b
-    tn = tile_n or min(_elem_tile(d), -(-rows // 8) * 8)
-    pad = (-rows) % tn
+    tile, groups = _elem_tile(rows, d)
+    pad = (-rows) % tile
     xr = jnp.pad(x.reshape(rows, d), ((0, pad), (0, 0)))
     yr = jnp.pad(y.reshape(rows, d), ((0, pad), (0, 0)))
-    spec = pl.BlockSpec((tn, d), lambda i: (i, 0))
+    span = d // groups
+    spec = pl.BlockSpec((tile, d), lambda i: (i, 0))
+    # The interpreter compiles a short loop body faster; the sums' order is
+    # the same at any unroll that is a multiple of _PARTS.
     out = pl.pallas_call(
-        _elem_kernel,
+        functools.partial(_elem_kernel, conv=mode == "conv", groups=groups,
+                          unroll=_PARTS if interpret else _UNROLL),
         name=f"circ_elem_{mode}",
-        grid=((rows + pad) // tn,),
+        grid=((rows + pad) // tile,),
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_elem_vmem_limit(tn, d)),
+        scratch_shapes=[
+            pltpu.VMEM((d, _LANES), jnp.float32),         # x, sideways
+            pltpu.VMEM((d + span, _LANES), jnp.float32),  # y's windows
+            pltpu.VMEM((span, _LANES), jnp.float32),      # result, sideways
+        ],
         interpret=interpret,
     )(xr, yr)
     return out[:rows].reshape(n, b, d)

@@ -29,7 +29,7 @@ def _circ_elem_dispatch(af: jax.Array, bf: jax.Array, mode: str) -> jax.Array:
 # Custom VJPs so the Pallas kernels are trainable. Circular-conv calculus:
 #   z = conv(a, b):  da = corr(b, g),  db = corr(a, g)
 #   z = corr(a, b):  da = corr(g, b),  db = conv(g, a)
-# — the backward pass reuses the same kernels (stays on the MXU path).
+# — the backward pass reuses the same kernels.
 
 
 @jax.custom_vjp

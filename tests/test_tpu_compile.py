@@ -58,13 +58,15 @@ def _compile(fn, sharding, *shapes):
 F32, I8 = jnp.float32, jnp.int8
 
 
-@pytest.mark.parametrize("d", [256, 512])
+@pytest.mark.parametrize("shape", [(2, 4, 256), (8, 4, 256), (64, 4, 256),
+                                   (16, 4, 512)])
 @pytest.mark.parametrize("mode", ["conv", "corr"])
-def test_circ_elem_compiles_for_v5e(one_chip, d, mode):
-    # NVSA serves 4 x 256 codes, MIMONet's published width is 512; 512
-    # needs the explicit scoped-VMEM limit _elem_vmem_limit sets
+def test_circ_elem_compiles_for_v5e(one_chip, shape, mode):
+    # the row counts the served path produces: NVSA's 4 x 256 codes in
+    # buckets of 2 and 8 problems and the 8-panel candidate bind, and
+    # MIMONet's published width 512
     _compile(lambda x, y: ck.circ_elem(x, y, mode=mode, interpret=False),
-             one_chip, ((64, 4, d), F32), ((64, 4, d), F32))
+             one_chip, (shape, F32), (shape, F32))
 
 
 @pytest.mark.parametrize("d", [256, 512])
