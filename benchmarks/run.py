@@ -1,4 +1,8 @@
-"""Benchmark harness — one section per paper table/figure.
+"""The paper's analytic tables — one section per paper table/figure.
+
+Every section runs the DSE, the analytic simulator or the recorded Tab. IV
+results (``benchmarks/bench_tables.py``); none times a device.  The chip
+benchmark is ``python3 -m bench.run`` (``BENCHMARK.json``).
 
 Prints ``name,us_per_call,derived`` CSV (one row per reported quantity) and
 writes results/bench_output.json.  A section that raises is reported as an
@@ -19,10 +23,6 @@ from benchmarks.bench_tables import (bench_fig1_characterization,
                                      bench_fig5_runtime, bench_fig6_ablation,
                                      bench_tab2_searchspace,
                                      bench_tab3_configs, bench_tab4_precision)
-from benchmarks.bench_kernels import bench_kernels
-from benchmarks.bench_nsai import bench_nsai
-from benchmarks.bench_roofline import bench_roofline
-from benchmarks.bench_serve import bench_serve
 
 SECTIONS = [
     ("tab2_searchspace", bench_tab2_searchspace),
@@ -31,17 +31,10 @@ SECTIONS = [
     ("fig1_characterization", bench_fig1_characterization),
     ("fig5_runtime_vs_baselines", bench_fig5_runtime),
     ("fig6_scalability_ablation", bench_fig6_ablation),
-    ("kernels_microbench", bench_kernels),
-    ("roofline_from_dryrun", bench_roofline),
-    ("serve_continuous_batching", bench_serve),
-    ("serve_nsai_reasoning", bench_nsai),
 ]
 
 
 def main() -> int:
-    from repro.common.util import enable_compile_cache
-
-    enable_compile_cache()
     all_rows = []
     failed = []
     print("name,us_per_call,derived")

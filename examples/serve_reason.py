@@ -3,13 +3,14 @@
 Every workload in ``configs.base.REASON_WORKLOADS`` serves through the SAME
 generic engine: its pipeline is compiled from declared stage functions into
 a ``StagedSchedule`` (``serve.schedule``), and ``ReasonEngine`` executes
-the schedule double-buffered so host ingest/staging of batch i+1 overlaps
+the schedule asynchronously so host ingest/staging of batch i+1 overlaps
 batch i on the device.
 
 Part 1: NVSA RAVEN serving — a lazy request stream flows through the
         compiled two-stage pipeline (ResNet frontend -> attribute PMFs;
         FPE codes -> VSA rule abduction -> circ-conv rule execution),
-        overlap vs sequential + per-stage timing breakdown.
+        staged (``overlap``, one dispatch per stage) vs ``fused`` (the
+        whole pipeline as one dispatch) on the same compiled schedule.
 Part 2: symbolic-stream-only serving (oracle variant) — accuracy 1.0 on
         unambiguous RAVEN grids by construction.
 Part 3: every registered workload through the same path — PrAE (PMF-table
@@ -23,8 +24,7 @@ Part 5: ONLINE mixed serving via ``repro.serve.deploy`` — an LM arch
         (stablelm-3b) and two NSAI workloads (nvsa + mimonet) deployed
         behind ONE deadline-batched, shape-bucketed front-door under
         Poisson arrivals.  The NSAI engines' serving knobs (batch
-        buckets, in-flight depth, overlap-vs-sequential schedule) are
-        DSE-derived from each workload's traced dataflow graph — the
+        buckets, in-flight depth) are DSE-derived from each workload's traced dataflow graph — the
         paper's generator -> architecture loop — and the report covers
         both request classes (tokens/s for LM rows, problems/s for NSAI
         rows) with per-request queue/service latency percentiles.
@@ -32,13 +32,14 @@ Part 5: ONLINE mixed serving via ``repro.serve.deploy`` — an LM arch
 Run:  PYTHONPATH=src python examples/serve_reason.py
 """
 
+import dataclasses
 import time
 
 import jax
 
 from repro.configs import base as cbase
 from repro.models import nvsa
-from repro.serve.reason import ReasonConfig
+from repro.serve.reason import ReasonConfig, ReasonEngine
 
 D = 64          # VSA block dim; >= 128 (pow2) would engage the Pallas kernel
 N_PROBLEMS = 16
@@ -52,21 +53,23 @@ def main():
     engine = cbase.reason_engine("nvsa", cfg, ReasonConfig(batch_size=BATCH),
                                  consts=consts)
 
-    # Part 1 — compiled NVSA pipeline, overlap vs sequential
+    # Part 1 — compiled NVSA pipeline, staged vs fused: a second engine
+    # over the same compiled schedules (jit caches live on them)
     print(f"[serve_reason] nvsa pipeline: "
           f"{engine.schedules['cnn'].describe()}")
+    fused = ReasonEngine(engine.schedules,
+                         dataclasses.replace(engine.cfg, schedule="fused"),
+                         consts=consts)
     stream, truth = entry.make_requests(cfg, N_PROBLEMS, seed=100)
     warm, _ = entry.make_requests(cfg, BATCH, seed=0)
-    engine.run(warm())  # warm up compile
-    engine.run(warm(), schedule="sequential")
-    for sched in ("sequential", "overlap"):
+    for eng in (engine, fused):
+        eng.run(warm())  # warm up compile
         t0 = time.time()
-        res = engine.run(stream(), schedule=sched)
+        res = eng.run(stream())
         dt = time.time() - t0
-        print(f"[serve_reason] nvsa/{sched}: {N_PROBLEMS} problems in "
-              f"{dt:.2f}s ({N_PROBLEMS / dt:.1f} problems/s)")
-    for name, t in engine.stats["stage_time_s"]["cnn"].items():
-        print(f"[serve_reason]   stage {name:10s} {t:.3f}s (sequential)")
+        print(f"[serve_reason] nvsa/{eng.cfg.schedule}: {N_PROBLEMS} "
+              f"problems in {dt:.2f}s, {eng.stats['dispatches']} "
+              f"dispatches for {eng.stats['batches']} groups")
     first = res[0]
     print(f"[serve_reason]   e.g. uid 0 (batch {first.batch}): answer "
           f"panel {first.answer}, logp {first.answer_logprobs.round(2)}")

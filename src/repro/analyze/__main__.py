@@ -9,7 +9,7 @@ dispatch floors, and the serving-source AST lint.  Exit code 0 iff no
 error-severity finding survives; warnings never fail the run.
 
 The CI ``static-analysis`` leg runs ``--workload all --format json`` and
-uploads the findings JSON next to the ``BENCH_*.json`` artifacts.
+uploads the findings JSON as an artifact.
 """
 
 from __future__ import annotations

@@ -19,8 +19,8 @@ composed pipeline back into this IR (``core.trace`` on the jaxpr): stage
 boundaries land on the nn/vsa/simd stream transitions modeled here, the
 per-stage buffer specs realize step ⑤, and the serving engine's
 double-buffered overlap of consecutive admission batches is the host/device
-realization of step ③ — ``interloop_overlap`` predicts the speedup that
-``benchmarks/bench_nsai.py`` measures on real traffic.
+realization of step ③ — ``interloop_overlap`` predicts its speedup
+(``serve.schedule.predicted_overlap``).
 """
 
 from __future__ import annotations

@@ -49,8 +49,8 @@ class DesignConfig:
 
     def tag(self) -> str:
         """Compact comma-free provenance tag (``HxWxN/nl:nv/mode``) —
-        recorded in BENCH_*.json rows and deployment reports so every
-        measurement says which DSE point served it."""
+        recorded in deployment summaries so every measurement says which
+        DSE point served it."""
         return (f"{self.H}x{self.W}x{self.N}"
                 f"/{self.nl_bar}:{self.nv_bar}/{self.mode}")
 
@@ -215,7 +215,6 @@ class ServingPlan:
     batch_size: int               # admission-group ceiling
     buckets: tuple[int, ...]      # compiled batch-size buckets, ascending
     max_inflight: int             # depth of the pipelined in-flight window
-    schedule: str                 # ReasonConfig knob: always overlap
     design: DesignConfig          # the DSE point the knobs derive from
 
 
@@ -223,14 +222,12 @@ def serving_plan(design: DesignConfig, max_batch: int = 8,
                  inflight_cap: int = 4, min_bucket: int = 2) -> ServingPlan:
     """Map an explored design point onto the serving runtime's knobs.
 
-    - **schedule**: every design serves with the ``overlap`` pipelined
-      schedule, so the host never blocks on a group stage by stage.
-      Algorithm 1's folded-vs-unfolded decision partitions an FPGA array;
-      on a device with an asynchronous queue a group's jitted stages run
-      in order whatever the host does, and blocking after each adds host
-      round trips and changes nothing on the device.  The mode still sets
-      the window depth below.  ``deploy()`` upgrades ``overlap`` to
-      ``fused`` where the fused negotiation is exact.
+    The design point sets no schedule: Algorithm 1's folded-vs-unfolded
+    decision partitions an FPGA array, while on a device with an
+    asynchronous queue a group's jitted stages run in order whatever the
+    host does.  ``deploy()`` serves ``fused`` or ``overlap`` from the
+    fused negotiation; the mode sets only the window depth below.
+
     - **batch buckets**: the admission width maps requests across the
       ``N`` sub-arrays, so the group ceiling is the largest power of two
       <= N (clamped to [min_bucket, max_batch]); the covering-bucket
@@ -262,7 +259,7 @@ def serving_plan(design: DesignConfig, max_batch: int = 8,
         depth = max(1, min(inflight_cap,
                            round(design.t_seq / max(1, design.t_para))))
     return ServingPlan(batch_size=batch, buckets=buckets, max_inflight=depth,
-                       schedule="overlap", design=design)
+                       design=design)
 
 
 # ---------------------------------------------------------------------------

@@ -53,7 +53,8 @@ class Device:
             + self.launch_overhead
 
 
-# Datasheet-derived constants (see benchmarks/bench_runtime_fig5.py table).
+# Datasheet-derived constants of the Fig. 5 baselines
+# (``benchmarks/bench_tables.py:bench_fig5_runtime`` reports against them).
 DEVICES = {
     "tx2": Device("Jetson TX2", 1.33e12, 59.7e9, 12e-6, False),
     "nx": Device("Xavier NX", 6.0e12, 51.2e9, 10e-6, False),

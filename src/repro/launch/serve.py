@@ -12,15 +12,14 @@ up here:
   N-stage ReasonEngine.  ``--model`` choices derive from the workload
   registry (``configs.base.REASON_WORKLOADS``: nvsa, prae, mimonet, lvrf);
   the pipeline is compiled from the workload's dataflow graph by
-  ``serve.schedule``, with the overlap/sequential schedule and Tab. IV
-  precision knobs exposed, and a per-stage timing breakdown printed for
-  the sequential schedule.
+  ``serve.schedule``, with the overlap/fused schedule and Tab. IV
+  precision knobs exposed.
 - ``--workload frontdoor``: *online mixed* serving through
   ``repro.serve.deploy`` — any mix of LM archs and NSAI workloads
   (``--models stablelm-3b,nvsa,mimonet``) behind one deadline-batched,
   shape-bucketed front-door fed by per-model Poisson arrival streams at
   ``--rate`` req/s.  The NSAI engines' serving knobs (batch buckets,
-  in-flight depth, schedule) are DSE-derived from each workload's traced
+  in-flight depth) are DSE-derived from each workload's traced
   dataflow graph under ``--max-pes``; the report covers both request
   classes (tokens/s for LM rows, problems/s for NSAI rows) plus
   per-model p50/p95/p99 queueing + service latency and bucket usage.
@@ -95,9 +94,6 @@ def serve_reason(args):
         split = " ".join(f"r{r['replica']}:{r['groups']}g/{r['requests']}req"
                          for r in engine.per_replica())
         print(f"[serve] {len(engine)} replicas: {split}")
-    if args.schedule == "sequential":
-        for name, t in engine.stats["stage_time_s"].get(variant, {}).items():
-            print(f"[serve]   stage {name:12s} {t:.3f}s")
     return results
 
 
@@ -255,7 +251,7 @@ def main():
                     choices=sorted(rt.TRAFFIC_CLASSES["reason"].models()))
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--schedule", default="overlap",
-                    choices=("overlap", "sequential", "fused"))
+                    choices=("overlap", "fused"))
     ap.add_argument("--d", type=int, default=128)
     ap.add_argument("--nn-precision", default="fp32",
                     choices=("fp32", "bf16", "int8", "int4"))

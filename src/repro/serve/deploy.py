@@ -15,9 +15,9 @@ actual serving path:
 3. **derive** — ``core.dse.serving_plan`` maps the winning design point
    onto the serving runtime's knobs (batch buckets, ``max_inflight``),
    and the engines are compiled from the *plan* instead of hand-set
-   ``ReasonConfig`` fields.  Every plan serves asynchronously: the
-   ``overlap`` schedule, upgraded to one ``fused`` dispatch per group
-   where the fused negotiation is exact.
+   ``ReasonConfig`` fields.  Every engine serves asynchronously: one
+   ``fused`` dispatch per group where the compiled schedule's fused
+   negotiation is exact, the staged ``overlap`` pipeline otherwise.
 
 LM workloads (token-in/token-out archs) have a single homogeneous nn
 stream — the dual-stream AdArray DSE has nothing to partition — so their
@@ -405,14 +405,15 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
     ``make_config`` knobs like ``d`` / ``nn_precision`` plus an optional
     ``variant`` and ``schedule``; LM: ``ServeConfig`` field overrides plus
     ``size``, ``"smoke"`` (default) or ``"full"`` for the arch's published
-    widths).  An NSAI ``schedule`` replaces the DSE-derived one;
-    ``"fused"`` serves one dispatch per group even where the fused
-    pipeline is only epsilon-equivalent to the staged one.
+    widths).  An NSAI ``schedule`` replaces the negotiated one (``fused``
+    where the fused pipeline is bit-identical to the staged one,
+    ``overlap`` otherwise); ``"fused"`` serves one dispatch per group
+    even where the fused pipeline is only epsilon-equivalent.
 
     For each NSAI workload the serving configuration is *derived*, not
     hand-set: the staged pipeline's dataflow graph is traced, explored by
     ``core.dse.explore`` under ``budget.max_pes``, and the winning design
-    point mapped to batch buckets / ``max_inflight`` / schedule by
+    point mapped to batch buckets / ``max_inflight`` by
     ``core.dse.serving_plan`` (see the module docstring).
 
     ``backend``: the kernel-lowering choice for the whole deployment —
@@ -500,20 +501,20 @@ def deploy(workloads: Iterable[str], traffic: Traffic | None = None,
             eng = cbase.reason_engine_pool(
                 m, cfg,
                 ReasonConfig(batch_size=plan.batch_size,
-                             schedule=schedule or plan.schedule,
+                             schedule=schedule or "overlap",
                              variant=variant,
                              max_inflight=plan.max_inflight,
                              buckets=plan.buckets),
                 consts=consts, variants=(variant,), replicas=r,
                 trace_graph=False, plan=lowering_plan,
                 fused=True if schedule == "fused" else "auto")
-            # fused-pipeline negotiation: when the compiled schedule's
-            # fused variant is provably bit-identical under the deployment
-            # plan, serve one dispatch per admission group instead of K;
-            # where it is only epsilon-equivalent the plan's ``overlap``
-            # stands and dispatches stage by stage (answers never change).
-            # Replicas share one compiled schedule but carry their own cfg
-            # copy, so the upgrade applies per replica.
+            # the served schedule, unless the options name one: one fused
+            # dispatch per admission group where the compiled schedule's
+            # fused variant is bit-identical under the deployment plan
+            # (``fused_ok``), the staged ``overlap`` pipeline where it is
+            # only epsilon-equivalent (answers never change).  Replicas
+            # share one compiled schedule but carry their own cfg copy,
+            # so the choice is set on each replica.
             subs = eng.replicas if hasattr(eng, "replicas") else [eng]
             if schedule is None and subs[0].schedules[variant].fused_ok:
                 for sub in subs:

@@ -668,9 +668,9 @@ class Engine:
 class LockstepEngine:
     """The seed engine: one XLA dispatch per token, greedy, no EOS handling.
 
-    Kept as the benchmark baseline for ``benchmarks/bench_serve.py`` — do not
-    use for serving (it predates the runtime protocol and takes params
-    explicitly).
+    Kept as the token-for-token reference that ``chip_smoke.py`` and the
+    tests compare the serving engine against — do not use for serving (it
+    predates the runtime protocol and takes params explicitly).
     """
 
     def __init__(self, decode_step: Callable, init_caches: Callable,

@@ -565,8 +565,8 @@ class FrontDoor:
 
         def close_group(model: str, reason: str):
             group = pending[model].pop(self._cap(model))
-            # closed when submit is called, not when it returns (under a
-            # synchronous schedule that would be the group's done time)
+            # closed when submit is called, not when it returns (a submit
+            # that trims the in-flight window blocks on an older group)
             with rt.Span("frontdoor.close", self._clock) as span:
                 rec = self.engines[model].submit([a.request for a in group])
                 span.annotate(group=rec.index)

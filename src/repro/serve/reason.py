@@ -13,8 +13,8 @@ engine.
 
 Admission groups flow through the compiled N-stage software pipeline with
 a configurable in-flight window (``ReasonConfig.max_inflight`` dispatched-
-but-undrained groups resident at once; 1 = PR 2's double buffering), so
-group *i*'s device stages overlap group *i+k*'s host work:
+but-undrained groups resident at once; 1 = double buffering), so group
+*i*'s device work overlaps group *i+k*'s host work:
 
     device:  S₁⁰..S₁ᴺ S₂⁰..S₂ᴺ S₃⁰.. ...       (async queue, never idle)
     host:     stage₂     stage₃     ...         (a window ahead)
@@ -24,21 +24,18 @@ Every host-side step — ingesting the next group from the request stream
 (which may be a lazy generator: rendering/preprocessing then runs inside
 the pipeline), staging device arrays, and converting finished answers back
 to numpy — runs while the device works through the in-flight window, so
-none of it sits on the critical path.  Dispatch is genuinely async: a new
-group's *entire* pipeline is enqueued on the device before the engine
-blocks on anything, and only then is the window trimmed back to
-``max_inflight`` by draining the oldest group (``jax.block_until_ready``
-happens solely at drain).  The ``fused`` schedule goes one step further
-and dispatches the whole pipeline as **one** jit call
-(``StagedSchedule.jit_fused``) when the schedule's fused variant was
-negotiated bit-identical to the staged one (``fused_ok``); otherwise it
-falls back to the per-stage dispatches and counts the group under
-``stats["fused_fallback_groups"]``.  The ``sequential`` schedule is the
-naive serve loop (synchronize after every stage, finish a group completely
-before touching the next) that ``bench_nsai.py`` compares against — the
-serving analogue of the paper's Fig. 9 folded-vs-unfolded comparison; it
-is also where the per-stage timing breakdown is measured (timing a stage
-requires blocking on it).
+none of it sits on the critical path.  A group is dispatched in one of two
+ways, and both are asynchronous: its *entire* pipeline is enqueued on the
+device before the engine blocks on anything, and only then is the window
+trimmed back to ``max_inflight`` by draining the oldest group
+(``jax.block_until_ready`` happens solely at drain).
+
+- ``overlap``: one jit call per stage (K dispatches a group).
+- ``fused``: the whole pipeline as **one** jit call
+  (``StagedSchedule.jit_fused``) where the schedule's fused variant was
+  negotiated bit-identical to the staged one (``fused_ok``); otherwise the
+  group is dispatched stage by stage and counted under
+  ``stats["fused_fallback_groups"]``.
 
 The engine implements the unified :class:`~repro.serve.runtime.
 EngineProtocol` natively — its workload constants (params / codebooks /
@@ -64,8 +61,7 @@ Stats are split so jit warmup cannot pollute throughput numbers: a run
 that compiles anything (first time a (variant, bucket) shape is executed)
 is accounted under ``stats["warmup"]``, steady-state runs under
 ``stats["measured"]`` (which ``problems_per_s`` reports), and per-run
-records (incl. a per-variant stage-time breakdown) append to
-``engine.runs``.
+records append to ``engine.runs``.
 """
 
 from __future__ import annotations
@@ -83,13 +79,13 @@ from repro.serve import runtime as rt
 from repro.serve.runtime import GroupRecord  # re-export (envelope lives there)
 from repro.serve.schedule import StagedSchedule
 
-SCHEDULES = ("overlap", "sequential", "fused")
+SCHEDULES = ("overlap", "fused")
 
 
 @dataclasses.dataclass
 class ReasonConfig:
     batch_size: int = 4           # max problems per admission group
-    schedule: str = "overlap"     # overlap | sequential | fused
+    schedule: str = "overlap"     # overlap | fused
     # Which compiled variant of the workload to run (e.g. "cnn" = neural
     # perception, "oracle" = ground-truth PMFs / symbolic-stream-only).
     # None = the first variant the engine was constructed with.
@@ -152,10 +148,6 @@ def _fresh_stats() -> dict:
         # groups served by the single fused jit vs groups that asked for
         # "fused" but fell back per-stage (schedule not negotiated exact)
         "fused_groups": 0, "fused_fallback_groups": 0,
-        # cumulative sequential-schedule stage times, keyed per variant so
-        # same-named stages of different variants (oracle vs cnn) never
-        # merge: {variant: {stage_name: seconds}}
-        "stage_time_s": {},
         # wall-time split: runs that compiled a new (variant, bucket)
         # shape land in "warmup", steady-state runs in "measured"
         # (``work`` == requests for reasoning traffic: one problem each)
@@ -174,17 +166,16 @@ class ReasonEngine:
     implements the consts-free :class:`~repro.serve.runtime.
     EngineProtocol` (``configs.base.reason_engine`` binds it for you).
     ``run(requests)`` feeds every request batch through the schedule's
-    stages.  ``clock`` is the timestamp source for
+    stages, one call per stage (``overlap``) or one fused call
+    (``fused``).  ``clock`` is the timestamp source for
     :class:`~repro.serve.runtime.GroupRecord`\\ s and the engine's layer
     spans (the front-door injects its own so queue/service latencies share
-    one origin), and so also for the per-stage times in
-    ``stats["stage_time_s"]``, which sum the ``reason.enqueue`` /
-    ``reason.wait`` spans; ``wall`` is the real wall-clock the throughput
-    accounting reads — separate so a virtual front-door clock never
-    distorts measured rates, injectable so the accounting itself is
-    testable.  ``device`` is where staged inputs
-    land (None = the default device): a replica passes the device its
-    ``consts`` live on, so its groups never route through device 0.
+    one origin); ``wall`` is the real wall-clock the throughput accounting
+    reads — separate so a virtual front-door clock never distorts measured
+    rates, injectable so the accounting itself is testable.  ``device`` is
+    where staged inputs land (None = the default device): a replica passes
+    the device its ``consts`` live on, so its groups never route through
+    device 0.
     """
 
     def __init__(self, schedules: StagedSchedule | Mapping[str, StagedSchedule],
@@ -225,7 +216,6 @@ class ReasonEngine:
         # jit and the staged jits have separate caches)
         self._warmed: set[tuple[str, int, str]] = set()
         self._cold_run = False
-        self._run_stage_time: dict[str, float] = {}
         self._in_run = False          # run() accounts at run level instead
         self._last_acct = float("-inf")  # busy-window edge for group stats
 
@@ -236,15 +226,12 @@ class ReasonEngine:
 
     # -- host-side staging --------------------------------------------------
 
-    def _resolve(self, schedule: str | None, variant: str | None):
-        schedule = schedule or self.cfg.schedule
+    def _resolve(self, variant: str | None):
         variant = variant or self.default_variant
-        if schedule not in SCHEDULES:
-            raise ValueError(f"unknown schedule {schedule!r}")
         if variant not in self.schedules:
             raise ValueError(f"unknown variant {variant!r}; "
                              f"compiled: {sorted(self.schedules)}")
-        return schedule, variant, self.schedules[variant]
+        return variant, self.schedules[variant]
 
     def _ingest(self, req: ReasonRequest, sched: StagedSchedule):
         try:
@@ -278,8 +265,7 @@ class ReasonEngine:
 
     def _collect(self, batch: list[ReasonRequest], out,
                  rec: GroupRecord, sched: StagedSchedule,
-                 cold: bool = False, t0: float | None = None,
-                 blocked: bool = False):
+                 cold: bool = False, t0: float | None = None):
         """Materialize one group's answers on the host (blocks if pending).
 
         Finished results land in the engine's ready buffer until a drain
@@ -291,14 +277,12 @@ class ReasonEngine:
         ``problems_per_s()`` reports a real measured rate for engines that
         never see ``run()``.
 
-        Unless the caller already ``blocked`` on the outputs (``sequential``
-        waits on every stage), the block on them is its own ``reason.wait``
-        span, ahead of the copy (``reason.collect``), so a wait under
-        ``overlap`` / ``fused`` is not hidden in ``np.asarray``."""
-        if not blocked:
-            with rt.Span("reason.wait", self.clock, rec, "wait_s",
-                         group=rec.index):
-                jax.block_until_ready(out)
+        The block on the outputs is its own ``reason.wait`` span, ahead of
+        the copy (``reason.collect``), so the wait is not hidden in
+        ``np.asarray``."""
+        with rt.Span("reason.wait", self.clock, rec, "wait_s",
+                     group=rec.index):
+            jax.block_until_ready(out)
         with rt.Span("reason.collect", self.clock, rec, "collect_s",
                      group=rec.index):
             host = jax.tree.map(np.asarray, out)
@@ -336,31 +320,26 @@ class ReasonEngine:
     # -- group-level API (the front-door drives these) ----------------------
 
     def submit(self, group: list[ReasonRequest],
-               schedule: str | None = None, variant: str | None = None
-               ) -> GroupRecord:
+               variant: str | None = None) -> GroupRecord:
         """Dispatch one admission group through the compiled pipeline.
 
-        Under ``overlap`` the stages are enqueued asynchronously and the
-        returned :class:`GroupRecord` has ``done_t=None``; the new group's
-        whole pipeline is dispatched *before* the engine blocks on
-        anything, and only then is the in-flight window trimmed back to
+        The group's whole pipeline is enqueued asynchronously and the
+        returned :class:`GroupRecord` has ``done_t=None``: under
+        ``overlap`` one jit call per stage, under ``fused`` one call of
+        the composed pipeline when the schedule negotiated its fused
+        variant substitutable (``fused_ok``), per-stage calls otherwise.
+        Only after the dispatch is the in-flight window trimmed back to
         ``cfg.max_inflight`` by draining the oldest group — its record
         (already returned by the earlier ``submit``) gets ``done_t``
         stamped in place, and its answers wait in the ready buffer for the
-        next ``drain_*`` call.  ``fused`` behaves like ``overlap`` but
-        dispatches the composed pipeline as one jit call when the schedule
-        negotiated its fused variant substitutable (``fused_ok``), falling
-        back to per-stage dispatch otherwise.  Under ``sequential`` the
-        group is served synchronously (accumulating the per-stage timing
-        breakdown) and returned complete.
+        next ``drain_*`` call.
         """
         consts = self.consts
         if consts is None:
             raise ValueError(
                 "engine has no consts bound — pass consts= to ReasonEngine "
                 "(configs.base.reason_engine binds them for you)")
-        schedule, variant, sched = self._resolve(schedule, variant)
-        sequential = schedule == "sequential"
+        variant, sched = self._resolve(variant)
         if not group:
             raise ValueError("empty admission group")
         if len(group) > self.cfg.batch_size:
@@ -378,7 +357,7 @@ class ReasonEngine:
         with rt.Span("reason.stage", self.clock, group=index) as staging:
             bufs, bucket = self._stage(group, sched)
         use_fused = False
-        if schedule == "fused":
+        if self.cfg.schedule == "fused":
             if sched.fused_ok:
                 use_fused = True
             else:
@@ -394,11 +373,10 @@ class ReasonEngine:
         rec = GroupRecord(uids=tuple(r.uid for r in group), index=index,
                           variant=variant, bucket=bucket, size=len(group))
         self._next_index += 1
-        stage_time = self.stats["stage_time_s"].setdefault(variant, {})
         t0 = self.wall()
-        # dispatch the whole pipeline asynchronously FIRST; any blocking
-        # (sequential timing, window trimming) happens after, so group i+1
-        # is always on the device before the engine waits on group i.
+        # dispatch the whole pipeline asynchronously FIRST; the window
+        # trimming happens after, so group i+1 is always on the device
+        # before the engine waits on group i.
         # Queueing ends where staging does: the rest is service
         rec.dispatch_t = staging.end
         if use_fused:
@@ -410,30 +388,16 @@ class ReasonEngine:
         else:
             for spec, fn in zip(sched.stages, sched.jit_stages):
                 with rt.Span("reason.enqueue", self.clock, rec, "enqueue_s",
-                             group=index, stage=spec.name) as enqueue:
+                             group=index, stage=spec.name):
                     bufs = fn(consts, bufs)
                 self.stats["dispatches"] += 1
-                if sequential:
-                    # a stage's time is its dispatch plus the block on it
-                    with rt.Span("reason.wait", self.clock, rec, "wait_s",
-                                 group=index, stage=spec.name) as wait:
-                        jax.block_until_ready(bufs)
-                    dt = enqueue.elapsed + wait.elapsed
-                    stage_time[spec.name] = \
-                        stage_time.get(spec.name, 0.0) + dt
-                    self._run_stage_time[spec.name] = \
-                        self._run_stage_time.get(spec.name, 0.0) + dt
         self.stats["batches"] += 1
-        if sequential:
-            self._collect(group, bufs, rec, sched, cold=cold, t0=t0,
-                          blocked=True)
-        else:
-            self._inflight.append((group, bufs, rec, sched, cold, t0))
-            # window backpressure: trim back down to max_inflight by
-            # draining the oldest group(s) — strictly after the new
-            # dispatch, so this wait is never the new group's service time
-            while len(self._inflight) > self.cfg.max_inflight:
-                self._drain_one()
+        self._inflight.append((group, bufs, rec, sched, cold, t0))
+        # window backpressure: trim back down to max_inflight by draining
+        # the oldest group(s) — strictly after the new dispatch, so this
+        # wait is never the new group's service time
+        while len(self._inflight) > self.cfg.max_inflight:
+            self._drain_one()
         return rec
 
     def _drain_one(self) -> GroupRecord | None:
@@ -496,32 +460,26 @@ class ReasonEngine:
     # -- the offline loop ---------------------------------------------------
 
     def run(self, requests: Iterable[ReasonRequest],
-            schedule: str | None = None, variant: str | None = None
-            ) -> dict[int, "ReasonResult"]:
+            variant: str | None = None) -> dict[int, "ReasonResult"]:
         """Serve all requests; returns {uid: ReasonResult}.
 
-        The offline loop over the group-level protocol: ``overlap`` —
-        pipelined: ingest/stage the next group while the device runs the
-        in-flight window, drain the oldest group's answers, then dispatch
-        the new group's stages asynchronously; host work never blocks the
-        device.  ``sequential``: synchronize after each stage, one group
-        at a time, accumulating the per-stage timing breakdown.
-        ``schedule`` / ``variant`` override the config per call (stage jit
-        caches live on the StagedSchedule, so benchmarks can compare
-        schedules on one engine instance).
+        The offline loop over the group-level protocol: ingest/stage the
+        next group while the device runs the in-flight window, dispatch
+        its pipeline asynchronously (``submit``), then drain; host work
+        never blocks the device.  ``variant`` overrides the config's per
+        call.
 
         Appends a per-run record to ``self.runs`` ({schedule, variant,
-        requests, wall_time_s, warmup, stage_time_s, problems_per_s});
-        runs that jit-compiled a new (variant, bucket) shape are flagged
-        ``warmup`` and excluded from the cumulative measured stats that
+        requests, wall_time_s, warmup, problems_per_s}); runs that
+        jit-compiled a new (variant, bucket) shape are flagged ``warmup``
+        and excluded from the cumulative measured stats that
         ``problems_per_s()`` reports.
         """
-        schedule, variant, _ = self._resolve(schedule, variant)
+        variant, _ = self._resolve(variant)
         if self._inflight or self._ready:
             raise ValueError("engine has undrained in-flight groups "
                              "(call drain_all first)")
         self._cold_run = False
-        self._run_stage_time = {}
         self._in_run = True   # account at run level, not per group
         t_start = self.wall()
         try:
@@ -529,7 +487,7 @@ class ReasonEngine:
                 # staging the next group (incl. any lazy per-request
                 # preprocessing in the `requests` iterable) overlaps the
                 # in-flight window on the device
-                self.submit(batch, schedule=schedule, variant=variant)
+                self.submit(batch, variant=variant)
             results = self.drain_all()
         finally:
             self._in_run = False
@@ -539,10 +497,9 @@ class ReasonEngine:
         self.stats[kind]["work"] += len(results)
         self.stats[kind]["wall_time_s"] += dt
         self.runs.append({
-            "schedule": schedule, "variant": variant,
+            "schedule": self.cfg.schedule, "variant": variant,
             "requests": len(results), "wall_time_s": dt,
             "warmup": self._cold_run,
-            "stage_time_s": dict(self._run_stage_time),
             "problems_per_s": len(results) / dt if dt else 0.0,
         })
         return results

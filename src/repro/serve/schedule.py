@@ -40,8 +40,8 @@ schedule (``stage_costs``) so benchmarks and tests can inspect both views.
 
 The correspondence with the analytical side: ``core.dataflow.build`` on the
 same graph drives the DSE; ``interloop_overlap`` predicts the steady-state
-pipeline speedup that ``benchmarks/bench_nsai.py`` measures on the compiled
-schedule (its overlap-vs-sequential gate).
+inter-loop pipeline speedup of the compiled schedule
+(``predicted_overlap``).
 """
 
 from __future__ import annotations
@@ -508,9 +508,9 @@ def predicted_overlap(schedule: StagedSchedule, n_batches: int = 2) -> dict:
 
     Splits the traced per-stage costs into the NN-stream prefix vs the
     symbolic tail and runs ``core.dataflow.interloop_overlap`` — the same
-    step-③ model the DSE uses — so benchmarks can print predicted next to
-    measured speedups.  Works on ``trace_graph=False`` schedules too:
-    stage costs are traced lazily on first use.
+    step-③ model the DSE uses.  Works on ``trace_graph=False`` schedules
+    too: stage costs (and with them the graph) are traced lazily on first
+    use.
     """
     _ensure_stage_costs(schedule)
     if not schedule.stage_costs:
@@ -520,12 +520,5 @@ def predicted_overlap(schedule: StagedSchedule, n_batches: int = 2) -> dict:
                zip(schedule.stages, schedule.stage_costs) if s.stream == "nn")
     t_sy = sum(sum(c["flops"].values()) for s, c in
                zip(schedule.stages, schedule.stage_costs) if s.stream != "nn")
-    if schedule.graph is not None:
-        return dfl.interloop_overlap(schedule.graph, max(1, t_nn),
-                                     max(1, t_sy), n_loops=n_batches)
-    stage = max(t_nn, t_sy, 1)
-    return {"pipelined": t_nn + (n_batches - 1) * stage + t_sy,
-            "sequential": n_batches * (t_nn + t_sy),
-            "speedup": (n_batches * (t_nn + t_sy)) /
-                       max(1, t_nn + (n_batches - 1) * stage + t_sy),
-            "bubble": 0.0}
+    return dfl.interloop_overlap(schedule.graph, max(1, t_nn), max(1, t_sy),
+                                 n_loops=n_batches)

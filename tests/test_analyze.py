@@ -638,7 +638,7 @@ def test_reason_engine_wall_is_injectable():
     consts = {"params": None,
               "books": nvsa.nvsa_codebooks(cfg, jax.random.PRNGKey(1))}
     eng = cbase.reason_engine(
-        "nvsa", cfg, ReasonConfig(batch_size=2, schedule="sequential"),
+        "nvsa", cfg, ReasonConfig(batch_size=2, schedule="overlap"),
         consts=consts, variants=("oracle",), trace_graph=False)
     eng.wall = _Ticker()
 

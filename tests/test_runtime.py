@@ -79,7 +79,6 @@ def test_serving_plan_maps_design_to_knobs():
                         nl_bar=8, nv_bar=8, t_para=100, t_seq=250,
                         t_phase1=100)
     plan = serving_plan(para, max_batch=8, inflight_cap=4)
-    assert plan.schedule == "overlap"
     assert plan.batch_size == 8            # pow2 floor of N=16, capped at 8
     assert plan.buckets == (2, 4, 8)
     assert plan.max_inflight == 2          # round(250/100), capped
@@ -88,17 +87,12 @@ def test_serving_plan_maps_design_to_knobs():
                        nl_bar=3, nv_bar=3, t_para=100, t_seq=90,
                        t_phase1=90)
     plan = serving_plan(seq, max_batch=8)
-    # the host never blocks per stage: an unfolded (sequential) design
-    # serves the pipelined schedule with a one-group window
-    assert plan.schedule == "overlap" and plan.max_inflight == 1
+    # an unfolded (sequential) design serves a one-group window
+    assert plan.max_inflight == 1
     assert plan.batch_size == 2 and plan.buckets == (2,)  # pow2 floor of 3
     # the inflight cap binds
     deep = serving_plan(para, max_batch=4, inflight_cap=1)
     assert deep.max_inflight == 1 and deep.batch_size == 4
-    # no design maps to the host-synchronous schedule
-    assert {serving_plan(d, max_batch=b, inflight_cap=c).schedule
-            for d in (para, seq) for b in (1, 2, 8) for c in (1, 4)} \
-        == {"overlap"}
 
 
 def test_deploy_selects_serving_config_from_dse(monkeypatch):
@@ -114,18 +108,16 @@ def test_deploy_selects_serving_config_from_dse(monkeypatch):
     design, plan = d.designs["nvsa"], d.plans["nvsa"]
     assert design.searched_points > 0          # explore actually ran
     expect = dse.serving_plan(design, max_batch=4, inflight_cap=4)
-    assert (plan.batch_size, plan.buckets, plan.max_inflight,
-            plan.schedule) == (expect.batch_size, expect.buckets,
-                               expect.max_inflight, expect.schedule)
+    assert (plan.batch_size, plan.buckets, plan.max_inflight) == \
+        (expect.batch_size, expect.buckets, expect.max_inflight)
     eng = d.engines["nvsa"]
     assert eng.cfg.batch_size == plan.batch_size
     assert eng.cfg.buckets == plan.buckets
     assert eng.cfg.max_inflight == plan.max_inflight
-    # deploy() upgrades a DSE "overlap" choice to the one-dispatch fused
-    # schedule when the fused negotiation came out exact; every other
-    # DSE choice stands as-is
-    upgraded = plan.schedule == "overlap" and eng.schedules["oracle"].fused_ok
-    assert eng.cfg.schedule == ("fused" if upgraded else plan.schedule)
+    # deploy() serves the one-dispatch fused schedule when the fused
+    # negotiation came out exact, the staged overlap one otherwise
+    assert eng.cfg.schedule == ("fused" if eng.schedules["oracle"].fused_ok
+                                else "overlap")
     assert eng.schedules["oracle"].batch_buckets == plan.buckets
     # the report records which DSE point serves (bench provenance)
     rec = d.report()["nvsa"]
@@ -139,10 +131,8 @@ def test_deploy_selects_serving_config_from_dse(monkeypatch):
     d2 = deploy(["nvsa"], budget=Budget(max_pes=1024, max_batch=4),
                 options={"nvsa": {"variant": "oracle", "d": 64}})
     assert d2.engines["nvsa"].cfg.buckets == (2,)      # pow2 floor of N=2
-    # the DSE chose "overlap"; nvsa's fused trace negotiates exact, so
-    # the deployment serves the one-dispatch fused schedule in its place
-    # (the recorded DSE plan keeps the original choice)
-    assert d2.plans["nvsa"].schedule == "overlap"
+    # nvsa's fused trace negotiates exact, so the deployment serves the
+    # one-dispatch fused schedule
     assert d2.engines["nvsa"].cfg.schedule == "fused"
     assert d2.engines["nvsa"].cfg.max_inflight == 2    # t_seq/t_para
 

@@ -6,6 +6,8 @@ bit-exact equivalence of the compiled NVSA schedule with PR 2's hand-wired
 two-stage pipeline, and served-vs-offline equivalence for the two workloads
 the refactor newly opened (MIMONet, LVRF)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +15,7 @@ import pytest
 
 from repro.configs import base as cbase
 from repro.nn import init as nninit
-from repro.serve.reason import ReasonConfig, ReasonRequest
+from repro.serve.reason import ReasonConfig, ReasonEngine, ReasonRequest
 from repro.serve.schedule import (STREAMS, StageSpec, StagedSchedule,
                                   _fmt_bytes, compile_schedule,
                                   predicted_overlap)
@@ -139,10 +141,6 @@ def test_mimonet_served_matches_offline():
         off_logp = shifted - np.log(np.exp(shifted).sum(-1, keepdims=True))
         np.testing.assert_allclose(res[i].answer_logprobs, off_logp,
                                    atol=1e-5)
-    # sequential run exposes the per-stage timing breakdown (per variant)
-    eng.run(factory(), schedule="sequential")
-    assert set(eng.stats["stage_time_s"]["default"]) == set(
-        eng.schedules["default"].stage_names)
 
 
 def test_lvrf_served_matches_offline(capsys):
@@ -242,16 +240,19 @@ def test_fused_schedule_bitexact_vs_staged(model, override):
         assert sched.fused_equivalence == "exact", (
             model, override, sched.fused_lowering_diff)
         assert sched.fused_ok
+        fused_eng = ReasonEngine(
+            eng.schedules, dataclasses.replace(eng.cfg, schedule="fused"),
+            consts=consts)
 
         factory, _ = entry.make_requests(cfg, 6, seed=11)
         reqs = list(factory())
-        staged = eng.run(iter(reqs), schedule="overlap")
+        staged = eng.run(iter(reqs))
         k = len(sched.jit_stages)
         assert eng.stats["dispatches"] == 2 * k       # 2 groups x K stages
-        fused = eng.run(iter(reqs), schedule="fused")
-        assert eng.stats["dispatches"] == 2 * k + 2   # 2 groups x 1 launch
-        assert eng.stats["fused_groups"] == 2
-        assert eng.stats["fused_fallback_groups"] == 0
+        fused = fused_eng.run(iter(reqs))
+        assert fused_eng.stats["dispatches"] == 2     # 2 groups x 1 launch
+        assert fused_eng.stats["fused_groups"] == 2
+        assert fused_eng.stats["fused_fallback_groups"] == 0
 
     assert set(staged) == set(fused)
     for uid, r_s in staged.items():
@@ -287,13 +288,16 @@ def test_fused_epsilon_negotiation_falls_back_stagewise():
         assert sched.fused_epsilon > 0
         assert "unbind_classify" in sched.fused_lowering_diff
         assert not sched.fused_ok
+        fused_eng = ReasonEngine(
+            eng.schedules, dataclasses.replace(eng.cfg, schedule="fused"),
+            consts=consts)
 
         factory, _ = entry.make_requests(cfg, 2, seed=0)
         reqs = list(factory())
-        staged = eng.run(iter(reqs), schedule="overlap")
-        fused = eng.run(iter(reqs), schedule="fused")
-        assert eng.stats["fused_groups"] == 0
-        assert eng.stats["fused_fallback_groups"] == 1
+        staged = eng.run(iter(reqs))
+        fused = fused_eng.run(iter(reqs))
+        assert fused_eng.stats["fused_groups"] == 0
+        assert fused_eng.stats["fused_fallback_groups"] == 1
     for uid in staged:
         np.testing.assert_array_equal(staged[uid].answer_logprobs,
                                       fused[uid].answer_logprobs)

@@ -53,16 +53,22 @@ def _serve(eng, reqs, clock):
     return door.serve(fd.trace_arrivals("nvsa", TIMES, reqs))
 
 
-@pytest.fixture(scope="module", params=["sequential", "overlap", "fused"])
+@pytest.fixture(scope="module",
+                params=[("overlap", 1), ("fused", 1), ("overlap", 2)],
+                ids=["overlap", "fused", "overlap-inflight2"])
 def served(request):
-    cfg, _, eng = _oracle_engine(schedule=request.param)
-    if request.param == "fused":
+    """A warm serve of three groups; the two-deep window trims after the
+    dispatch that would make it three deep."""
+    schedule, max_inflight = request.param
+    cfg, _, eng = _oracle_engine(schedule=schedule,
+                                 max_inflight=max_inflight)
+    if schedule == "fused":
         assert eng.schedules["oracle"].fused_ok
     _serve(eng, _oracle_requests(cfg, len(TIMES), seed=7),
            TickingClock())  # warm
     eng.reset_stats()
     rep = _serve(eng, _oracle_requests(cfg, len(TIMES)), TickingClock())
-    return request.param, eng, rep
+    return schedule, eng, rep
 
 
 def test_queue_split_sums_to_queue_time(served):
@@ -84,9 +90,9 @@ def test_service_split_within_service_time(served):
 
 
 def test_close_stamped_before_dispatch(served):
-    """The close stamp is taken when ``submit`` is called: under the
-    synchronous schedule a stamp taken on its return would read the
-    group's done time."""
+    """The close stamp is taken when ``submit`` is called: a ``submit``
+    that trims the in-flight window blocks on an older group after the
+    dispatch, so a stamp taken on its return would read after it."""
     _, _, rep = served
     for g in rep.groups:
         assert g.close_s < g.dispatch_s < g.done_s
@@ -96,16 +102,20 @@ def test_close_stamped_before_dispatch(served):
         assert lat.arrival_s <= lat.admit_s <= lat.close_s <= lat.dispatch_s
 
 
-def test_stage_time_keeps_its_keys(served):
-    schedule, eng, _ = served
-    st = eng.stats["stage_time_s"]
-    assert set(st) == {"oracle"}
-    if schedule == "sequential":
-        names = {s.name for s in eng.schedules["oracle"].stages}
-        assert set(st["oracle"]) == names
-        assert all(v > 0 for v in st["oracle"].values())
-    else:
-        assert st["oracle"] == {}
+def test_counters_match_the_served_path(served):
+    """The counters the benchmark reads: one dispatch a group under
+    ``fused``, one per stage under ``overlap``, and every group counted
+    fused or fallen back only when the schedule asks for ``fused``."""
+    schedule, eng, rep = served
+    sched = eng.schedules["oracle"]
+    groups = eng.stats["batches"]
+    assert groups == len(rep.groups) == 3
+    per_group = 1 if schedule == "fused" else len(sched.stages)
+    assert eng.stats["dispatches"] == per_group * groups
+    fused = groups if schedule == "fused" else 0
+    assert eng.stats["fused_groups"] == (fused if sched.fused_ok else 0)
+    assert eng.stats["fused_fallback_groups"] == \
+        (0 if sched.fused_ok else fused)
 
 
 def test_sim_engine_groups_keep_defaults():
@@ -187,15 +197,23 @@ def test_profiler_trace_has_the_spans(tmp_path):
                                 for c, d in close_iv)
 
 
-def test_sequential_waits_once_per_stage(tmp_path):
-    """Under ``sequential`` each stage's block is the group's only wait:
-    the collect does not block on the outputs again."""
-    eng, spans = _traced_spans(tmp_path, "sequential")
+@pytest.mark.parametrize("schedule", ["overlap", "fused"])
+def test_wait_once_per_group(tmp_path, schedule):
+    """A group's one block on the device is the ``reason.wait`` before its
+    collect, with no stage tag; its dispatches are one ``reason.enqueue``
+    per stage, or one fused call."""
+    eng, spans = _traced_spans(tmp_path, schedule)
     waits: dict = {}
+    enqueues: dict = {}
     for e in spans:
+        stats = dict(e.stats)
         if e.name == "reason.wait":
-            stats = dict(e.stats)
-            waits.setdefault(stats["group"], []).append(stats["stage"])
-    names = [s.name for s in eng.schedules["oracle"].stages]
-    assert len(waits) == 3
-    assert all(stages == names for stages in waits.values())
+            assert "stage" not in stats
+            waits[stats["group"]] = waits.get(stats["group"], 0) + 1
+        elif e.name == "reason.enqueue":
+            enqueues.setdefault(stats["group"], []).append(stats["stage"])
+    stages = ["fused"] if schedule == "fused" else \
+        [s.name for s in eng.schedules["oracle"].stages]
+    assert len(waits) == 3 and set(waits.values()) == {1}
+    assert enqueues.keys() == waits.keys()
+    assert all(names == stages for names in enqueues.values())
